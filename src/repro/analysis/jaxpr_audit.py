@@ -18,13 +18,18 @@ JX102  packed int8/uint8 planes are never `convert_element_type`'d to
 JX103  every Pallas block shape divides its operand's array shape —
        ragged tails would silently read OOB-masked garbage or force
        masking the kernels don't do.
-JX104  in a program that declares a page size, any rank-4 packed-plane
-       block must tile the page axis exactly (`block[1] == page_size`) —
-       the paged kernels gather whole pages via the block table, and a
-       mismatched tile (e.g. replay forgetting `attn_bk = page_size`)
-       reads across page boundaries.
-JX105  the summed block footprint of a `pallas_call` stays under the
-       VMEM budget — all operand tiles are resident per grid step.
+JX104  in a program that declares a page size, every block of a
+       lane-dense packed plane (`[..., rows, KV*hd]` int8) is one whole
+       page of every local head: `block[1] == page_size` and
+       `block[2] == KV*hd`. The paged kernels gather whole pages via the
+       block table, so a mismatched row tile (e.g. replay forgetting
+       `attn_bk = page_size`) reads across page boundaries; a tile of
+       part of the lane axis splits heads, which the TPU tiling rule
+       refuses for sub-128 head groups.
+JX105  the VMEM footprint of a `pallas_call` stays under the budget:
+       every pipelined operand tile double-buffered, plus scratch, each
+       padded to the TPU's (sublane, 128-lane) tile — a (16, 64) int8
+       tile occupies a (32, 128) slot.
 JX106  re-tracing a program under the engine's real shape set yields
        ONE jit signature — the static generalization of the
        compile-count regression guard.
@@ -48,15 +53,30 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax._src import source_info_util
+from jax.experimental import pallas as pl
 
 from repro.analysis.findings import (Finding, JX_COMPILE_CACHE, JX_HOSTCALL,
                                      JX_PACKED_CAST, JX_PAGE_TILE,
                                      JX_TILE_DIVIDE, JX_VMEM)
 
-#: default per-kernel operand-tile budget. TPU cores carry ~16 MiB of
-#: VMEM shared between operand tiles, scratch, and double-buffering;
-#: capping visible tiles at a quarter of that leaves headroom for both.
+#: default per-kernel VMEM budget for the padded, double-buffered tiles
+#: and scratch JX105 counts. The v5e's default scoped VMEM limit is
+#: 16 MiB; a quarter of it leaves headroom for the kernel body's own
+#: temporaries, which the estimate does not see.
 DEFAULT_VMEM_BUDGET = 4 * 1024 * 1024
+
+
+def vmem_tile_bytes(dims: Sequence[int], dtype) -> int:
+    """Bytes one array tile occupies in TPU VMEM: the last dim padded to
+    128 lanes and the second-minor to the dtype's sublane tile (8 rows of
+    32-bit words: 8 f32, 16 bf16 or 32 int8 rows)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    d = list(dims) or [1]
+    d[-1] = -(-d[-1] // 128) * 128
+    if len(d) >= 2:
+        sub = 8 * max(1, 4 // itemsize)
+        d[-2] = -(-d[-2] // sub) * sub
+    return math.prod(d) * itemsize
 
 _HOSTCALL_PRIMS = frozenset(
     {"pure_callback", "io_callback", "debug_callback", "callback"})
@@ -83,7 +103,7 @@ class ProgramSpec:
 
 
 def _frame(eqn) -> Tuple[str, int]:
-    fr = source_info_util.user_frame(eqn.source_info)
+    fr = source_info_util.user_frame(eqn.source_info.traceback)
     if fr is None:
         return "", 0
     return fr.file_name, fr.start_line
@@ -157,28 +177,26 @@ class _Auditor:
 
     # ------------------------------------------------------------- pallas
     def _block_dims(self, bm) -> List[Optional[int]]:
+        """Block extents of one BlockMapping: `Blocked(n)` gives n, a
+        squeezed dim is one element, other indexing modes are skipped."""
         dims: List[Optional[int]] = []
-        for d in getattr(bm, "block_shape", ()) or ():
-            try:
-                dims.append(int(d))
-            except (TypeError, ValueError):
-                dims.append(None)      # squeezed / symbolic dim: skip
+        for d in bm.block_shape:
+            size = 1 if isinstance(d, pl.Squeezed) else d.block_size
+            dims.append(size if isinstance(size, int) else None)
         return dims
 
     def _check_pallas(self, eqn) -> None:
         gm = eqn.params.get("grid_mapping")
         if gm is None:
             return
-        total_bytes = 0
-        for bm in getattr(gm, "block_mappings", ()) or ():
-            sds = getattr(bm, "array_shape_dtype", None)
-            if sds is None:
-                continue
-            shape, dtype = tuple(sds.shape), str(sds.dtype)
+        vmem = 0
+        for bm in gm.block_mappings:
+            shape = tuple(bm.array_aval.shape)
+            dtype = str(bm.array_aval.dtype)
             dims = self._block_dims(bm)
-            itemsize = jnp.dtype(dtype).itemsize
-            total_bytes += math.prod(d for d in dims
-                                     if isinstance(d, int)) * itemsize
+            if getattr(bm.block_aval, "memory_space", None) is None:
+                vmem += 2 * vmem_tile_bytes(         # double-buffered
+                    [d if isinstance(d, int) else 1 for d in dims], dtype)
             bad = [(i, b, s) for i, (b, s) in enumerate(zip(dims, shape))
                    if isinstance(b, int) and b > 0 and s % b]
             if bad:
@@ -188,18 +206,24 @@ class _Auditor:
                            f"operand shape {shape} (dim {i}: {s} % {b} "
                            f"!= 0)")
             if (self.page_size is not None and dtype in _PACKED_DTYPES
-                    and len(shape) == 4 and len(dims) >= 2
-                    and isinstance(dims[1], int)
-                    and dims[1] != self.page_size):
+                    and len(shape) == 3
+                    and (dims[1], dims[2]) != (self.page_size, shape[2])):
                 self._emit(JX_PAGE_TILE, eqn,
                            f"packed plane {shape} {dtype} tiled with "
-                           f"block[1]={dims[1]} but program page_size="
+                           f"block {tuple(dims)} but program page_size="
                            f"{self.page_size} — paged kernels must tile "
-                           f"whole pages (attn_bk == page_size)")
-        if total_bytes > self.vmem_budget:
+                           f"whole pages of every local head (block "
+                           f"(1, page_size, KV*hd), attn_bk == page_size)")
+        n_scratch = gm.num_scratch_operands
+        kernel_in = eqn.params["jaxpr"].invars
+        for v in kernel_in[len(kernel_in) - n_scratch:]:
+            aval = getattr(v.aval, "inner_aval", v.aval)
+            vmem += vmem_tile_bytes(aval.shape, aval.dtype)
+        if vmem > self.vmem_budget:
             self._emit(JX_VMEM, eqn,
-                       f"estimated operand-tile footprint {total_bytes} B "
-                       f"exceeds VMEM budget {self.vmem_budget} B")
+                       f"estimated VMEM footprint {vmem} B (padded, "
+                       f"double-buffered tiles + scratch) exceeds budget "
+                       f"{self.vmem_budget} B")
 
     # --------------------------------------------------------------- walk
     def walk(self, jaxpr, taint_in: Sequence[bool],

@@ -233,7 +233,7 @@ def _dispatcher_specs(model) -> List[ProgramSpec]:
 
     chunked = functools.partial(ops.sparq_chunked_prefill_attention,
                                 impl="pallas", bq=ALIGN)
-    pool = _sds((P, ps, KV, hd), i8)
+    pool = _sds((P, ps, KV * hd), i8)          # lane-dense page pool
     specs.append(ProgramSpec(
         "ops.sparq_chunked_prefill_attention", chunked,
         [(_sds((CHUNK, H, hd), f32), _sds((CHUNK, KV, hd), f32),

@@ -260,13 +260,14 @@ def constrain_last(x: jnp.ndarray) -> jnp.ndarray:
 # ----------------------------------------------------------------------
 
 def pool_plane_pspec(ndim: int) -> P:
-    """PartitionSpec for one packed §5.1 page-pool plane: the KV-head
-    axis (always ndim-2: [..., P, ps, KV, hd]) shards over the model
-    axis, everything else — pages, rows, head_dim, an optional leading
-    layer-stack axis — is replicated. Head groups never split because
-    the engine validates n_kv_heads % tp == 0 up front."""
+    """PartitionSpec for one packed §5.1 page-pool plane: the lane axis
+    (always the last: [..., P, ps, KV*hd], KV-major) shards over the
+    model axis, so each device holds KV/tp whole heads; everything else
+    — pages, rows, an optional leading layer-stack axis — is replicated.
+    Head groups never split because the engine validates
+    n_kv_heads % tp == 0 up front."""
     entries = [None] * ndim
-    entries[ndim - 2] = TP
+    entries[ndim - 1] = TP
     return P(*entries)
 
 

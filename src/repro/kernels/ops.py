@@ -19,7 +19,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.quantizer import QScale
@@ -30,7 +29,7 @@ from repro.kernels.sparq_decode_attn import (sparq_decode_attn_pallas,
 from repro.kernels.sparq_dequant import sparq_dequant_pallas
 from repro.kernels.sparq_prefill_attn import sparq_chunked_prefill_attn_pallas
 from repro.kernels.sparq_matmul import sparq_matmul_pallas
-from repro.kernels.sparq_quant import sparq_quant_pallas
+from repro.kernels.sparq_quant import row_block, sparq_quant_pallas
 
 
 def _on_tpu() -> bool:
@@ -140,10 +139,36 @@ def quantized_matmul(
     cfg: SparqConfig,
     impl: str = "auto",
     block: tuple[int, int, int] = (128, 128, 512),
+    mesh: Optional[Mesh] = None,
 ) -> jnp.ndarray:
-    """SPARQ-quantized x @ dequant(w). Leading dims of x are flattened."""
+    """SPARQ-quantized x @ dequant(w). Leading dims of x are flattened.
+
+    With a tensor-parallel `mesh`, the Pallas kernel runs under a
+    shard_map (the compiler cannot partition a Mosaic kernel): each
+    device computes its N/tp output columns from the whole x and its
+    columns of w, then all-gathers them. A column's K-summation does not
+    depend on the other columns, so the result is bit-identical to TP=1.
+    The result leaves replicated: a column-sharded output would let
+    GSPMD split later reductions over N (an RMSNorm's sum) into partial
+    sums, which changes their order. When tp does not divide N every
+    device computes the whole product."""
     if impl == "auto":
         impl = "pallas" if _on_tpu() else "reference"
+    tp = tp_size(mesh)
+    if impl == "pallas" and tp > 1:
+        split = w_codes.shape[1] % tp == 0
+
+        def body(x, w_codes, scale, chan_scale):
+            qs = QScale(scale=scale, bits=act_qs.bits, signed=act_qs.signed)
+            out = quantized_matmul(x, w_codes, qs, chan_scale, cfg,
+                                   impl=impl, block=block)
+            return jax.lax.all_gather(out, TP_AXIS, axis=out.ndim - 1,
+                                      tiled=True) if split else out
+        col = TP_AXIS if split else None
+        return jax.shard_map(
+            body, mesh=mesh, in_specs=(P(), P(None, col), P(), P(col)),
+            out_specs=P(), check_vma=False)(
+            x, w_codes, jnp.asarray(act_qs.scale), chan_scale)
     lead = x.shape[:-1]
     K = x.shape[-1]
     N = w_codes.shape[1]
@@ -174,7 +199,7 @@ def sparq_quantize(
     act_qs: QScale,
     cfg: SparqConfig,
     impl: str = "auto",
-    bm: int = 256,
+    bm: Optional[int] = None,
 ):
     """Standalone SPARQ quantization (KV-cache write path).
 
@@ -199,6 +224,7 @@ def sparq_quantize(
         codes, meta = _ref.ref_sparq_quant(x2, act_qs.scale, **kw)
     else:
         M = x2.shape[0]
+        bm = bm or row_block(K)
         xp = _pad_to(x2, bm, 0)
         codes, meta = sparq_quant_pallas(
             xp, jnp.asarray(act_qs.scale, jnp.float32),
@@ -224,7 +250,7 @@ def sparq_dequantize(
     store: jnp.ndarray,       # (..., K) int8 window codes
     meta: jnp.ndarray,        # (..., K) int8 packed meta bytes
     impl: str = "auto",
-    bm: int = 256,
+    bm: Optional[int] = None,
 ) -> jnp.ndarray:
     """Meta-decode (KV-cache read fallback): (store, meta) -> int8 codes.
 
@@ -243,6 +269,7 @@ def sparq_dequantize(
         codes = _ref.ref_sparq_dequant(s2, m2)
     else:
         M = s2.shape[0]
+        bm = bm or row_block(K)
         codes = sparq_dequant_pallas(
             _pad_to(s2, bm, 0), _pad_to(m2, bm, 0),
             bm=bm, interpret=not _on_tpu())[:M]
@@ -292,10 +319,10 @@ def sparq_decode_attention(
         head = P(None, None, TP_AXIS, None)
         body = functools.partial(
             sparq_decode_attention, window=window, impl=impl, bk=bk)
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(head, head, head, P(), head, head, P(), P(), P()),
-            out_specs=head, check_rep=False,
+            out_specs=head, check_vma=False,
         )(q, k_data, k_meta, k_scale, v_data, v_meta, v_scale, kpos, cur)
     if impl == "auto":
         impl = "pallas" if _on_tpu() else "reference"
@@ -322,9 +349,10 @@ def sparq_decode_attention(
         out = _ref.ref_sparq_decode_attn(
             qg, kd, km, ks, vd, vm, vs, kp, cur, window=window, bk=bk)
     elif impl == "pallas":
+        flat = lambda x: x.reshape(B, x.shape[1], KV * hd)
         out = sparq_decode_attn_pallas(
-            qg, kd, km, ks, vd, vm, vs, kp, cur, window=window, bk=bk,
-            interpret=not _on_tpu())
+            qg, flat(kd), flat(km), ks, flat(vd), flat(vm), vs, kp, cur,
+            window=window, bk=bk, interpret=not _on_tpu())
     else:
         raise ValueError(impl)
     return out.reshape(B, 1, H, hd)
@@ -334,8 +362,8 @@ def sparq_chunked_prefill_attention(
     q: jnp.ndarray,            # (C, H, hd) float — one chunk of queries
     k_chunk: jnp.ndarray,      # (C, KV, hd) float — chunk K (pre-quant)
     v_chunk: jnp.ndarray,      # (C, KV, hd) float
-    k_data: jnp.ndarray,       # (P, ps, KV, hd) int8 window-code pool
-    k_meta: jnp.ndarray,       # (P, ps, KV, hd) int8 meta-byte pool
+    k_data: jnp.ndarray,       # (P, ps, KV*hd) int8 window-code pool
+    k_meta: jnp.ndarray,       # (P, ps, KV*hd) int8 meta-byte pool
     k_scale: jnp.ndarray,      # (S,) f32 per-slot site scales
     v_data: jnp.ndarray,
     v_meta: jnp.ndarray,
@@ -367,22 +395,22 @@ def sparq_chunked_prefill_attention(
     With `mesh`, heads/pools shard over the "model" axis (see tp_size)."""
     tp = tp_size(mesh)
     if tp > 1:
-        _tp_guard(k_data.shape[2], tp)
+        _tp_guard(k_chunk.shape[1], tp)
         h2 = P(None, TP_AXIS, None)       # (C, H, hd) streams
-        h3 = P(None, None, TP_AXIS, None)  # (P, ps, KV, hd) pools
+        h3 = P(None, None, TP_AXIS)       # (P, ps, KV*hd) pools
         body = functools.partial(
             sparq_chunked_prefill_attention, window=window, impl=impl, bq=bq)
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(h2, h2, h2, h3, h3, P(), h3, h3, P(),
                       P(), P(), P(), P(), P()),
-            out_specs=h2, check_rep=False,
+            out_specs=h2, check_vma=False,
         )(q, k_chunk, v_chunk, k_data, k_meta, k_scale, v_data, v_meta,
           v_scale, block_table, seq_id, pos, hist, tile_seq)
     if impl == "auto":
         impl = "pallas" if _on_tpu() else "reference"
     C, H, hd = q.shape
-    KV = k_data.shape[2]
+    KV = k_chunk.shape[1]
     G = H // KV
     assert C % bq == 0, (C, bq)
     qg = q.reshape(C, KV, G, hd)
@@ -405,8 +433,8 @@ def sparq_chunked_prefill_attention(
 
 def sparq_paged_decode_attention(
     q: jnp.ndarray,            # (B, 1, H, hd) float, one token per sequence
-    k_data: jnp.ndarray,       # (P, ps, KV, hd) int8 window-code page pool
-    k_meta: jnp.ndarray,       # (P, ps, KV, hd) int8 packed meta-byte pool
+    k_data: jnp.ndarray,       # (P, ps, KV*hd) int8 window-code page pool
+    k_meta: jnp.ndarray,       # (P, ps, KV*hd) int8 packed meta-byte pool
     k_scale: jnp.ndarray,      # (B,) f32 per-sequence site scale
     v_data: jnp.ndarray,
     v_meta: jnp.ndarray,
@@ -435,22 +463,23 @@ def sparq_paged_decode_attention(
     Returns f32 (B, 1, H, hd). With `mesh`, pools and heads shard over
     the "model" axis; block table / cur / scales stay replicated."""
     tp = tp_size(mesh)
+    B, Tq, H, hd = q.shape
+    KV = k_data.shape[-1] // hd
     if tp > 1:
-        _tp_guard(k_data.shape[2], tp)
+        _tp_guard(KV, tp)
         head = P(None, None, TP_AXIS, None)
+        pool = P(None, None, TP_AXIS)
         body = functools.partial(
             sparq_paged_decode_attention, window=window, impl=impl)
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
-            in_specs=(head, head, head, P(), head, head, P(), P(), P()),
-            out_specs=head, check_rep=False,
+            in_specs=(head, pool, pool, P(), pool, pool, P(), P(), P()),
+            out_specs=head, check_vma=False,
         )(q, k_data, k_meta, k_scale, v_data, v_meta, v_scale,
           block_table, cur)
     if impl == "auto":
         impl = "pallas" if _on_tpu() else "reference"
-    B, Tq, H, hd = q.shape
     assert Tq == 1, f"decode attention takes one query token, got Tq={Tq}"
-    KV = k_data.shape[2]
     G = H // KV
     qg = q.reshape(B, KV, G, hd)
     bt = block_table.astype(jnp.int32)

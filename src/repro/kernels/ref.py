@@ -2,11 +2,21 @@
 repro.core; these wrappers match the kernels' exact signatures/dtypes)."""
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from repro.core.bsparq import bsparq_encode
 from repro.core.sparq import SparqConfig, sparq_recon_int
+
+
+def _as_read(*scales):
+    """The oracle's scale operands as given. A kernel reads its scales
+    from memory; an oracle fused with their producers (`s / qmax`,
+    `max|w| / 127`) lets XLA fold those constants into its own
+    arithmetic, which moves some outputs by an ulp."""
+    return jax.lax.optimization_barrier(scales)
 
 
 def _cfg(bits, shifts, rounding, vsparq, signed, max_val, enabled=True):
@@ -20,6 +30,7 @@ def ref_sparq_matmul(x, w_codes, act_scale, chan_scale, *, bits=4,
                      opts_shifts=(0, 1, 2, 3, 4), rounding=True, vsparq=True,
                      signed=False, max_val=255, enabled=True):
     """Oracle for sparq_matmul_pallas: float x, int8 weight codes."""
+    act_scale, chan_scale = _as_read(act_scale, chan_scale)
     qmin = -max_val if signed else 0
     q = jnp.clip(jnp.round(x.astype(jnp.float32) / act_scale), qmin, max_val)
     q = q.astype(jnp.int32)
@@ -38,13 +49,16 @@ def ref_sparq_matmul(x, w_codes, act_scale, chan_scale, *, bits=4,
             r, w_codes.astype(jnp.int32),
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32)
-    return (acc.astype(jnp.float32) * act_scale * chan_scale[None, :])
+    # one f32 scale per column, then one rounding per output: the
+    # kernel's order (XLA regroups (acc * a) * c into it on some backends)
+    return acc.astype(jnp.float32) * (act_scale * chan_scale)[None, :]
 
 
 def ref_sparq_quant(x, act_scale, *, bits=4, opts_shifts=(0, 1, 2, 3, 4),
                     rounding=True, vsparq=True, signed=True, max_val=127,
                     enabled=True):
     """Oracle for sparq_quant_pallas: returns (codes int8, meta int8)."""
+    (act_scale,) = _as_read(act_scale)
     qmin = -max_val if signed else 0
     q = jnp.clip(jnp.round(x.astype(jnp.float32) / act_scale), qmin, max_val)
     q = q.astype(jnp.int32)
@@ -101,6 +115,39 @@ def _meta_decode32(store, meta, scale):
     return recon.astype(jnp.float32) * scale
 
 
+#: the attention oracles' f32 contractions at full f32 precision (the
+#: kernels' dots are f32 too): a no-op on CPU, and on TPU it keeps XLA
+#: from running them as single bf16 passes, which would make the oracle
+#: a coarser computation than the kernel it checks
+_F32 = jax.lax.Precision.HIGHEST
+
+
+def chunk_key_tile(C: int) -> int:
+    """Keys per flash update in the chunked-prefill in-chunk stage: 128
+    (one MXU pass per contraction) when it divides the chunk, else the
+    whole chunk."""
+    return 128 if C % 128 == 0 else C
+
+
+def row_sum(p: jnp.ndarray) -> jnp.ndarray:
+    """Sum of p over its last axis, keepdims, as a 2-D MXU contraction
+    with ones. The kernels and the oracles both take it here: on a TPU a
+    lane reduction sums in an order that Mosaic and XLA choose
+    differently, while a dot over at most 128 keys (one MXU pass) sums
+    alike in both."""
+    keys = p.shape[-1]
+    ones = jnp.ones((keys, 128), jnp.float32)
+    s = jax.lax.dot_general(
+        p.reshape(-1, keys), ones, (((1,), (0,)), ((), ())),
+        precision=_F32, preferred_element_type=jnp.float32)
+    return s[:, :1].reshape(*p.shape[:-1], 1)
+
+
+def _heads(n_kv: int, plane):
+    """Lane-dense [..., KV*hd] plane (gathered pages) -> [..., KV, hd]."""
+    return plane.reshape(*plane.shape[:-1], n_kv, plane.shape[-1] // n_kv)
+
+
 def ref_sparq_decode_attn(q, k_data, k_meta, k_scale, v_data, v_meta,
                           v_scale, kpos, cur, *, window: int = 0,
                           bk: int = 128):
@@ -113,6 +160,7 @@ def ref_sparq_decode_attn(q, k_data, k_meta, k_scale, v_data, v_meta,
     q [B,KV,G,hd] float; k/v planes [B,Tk,KV,hd] int8; kpos [B,Tk] int32
     slot positions (-1 = empty); cur scalar int32. Returns f32 [B,KV,G,hd].
     """
+    k_scale, v_scale = _as_read(k_scale, v_scale)
     B, KV, G, hd = q.shape
     Tk = k_data.shape[1]
     assert Tk % bk == 0, (Tk, bk)
@@ -130,7 +178,8 @@ def ref_sparq_decode_attn(q, k_data, k_meta, k_scale, v_data, v_meta,
         kp = jax.lax.dynamic_slice_in_dim(kpos, t * bk, bk, 1)  # [B, bk]
         k = _decode(kd, km, k_scale)                   # [B, bk, KV, hd]
         s = jnp.einsum("bkgh,bskh->bkgs", qf, k,
-                       preferred_element_type=jnp.float32) * sm_scale
+                       preferred_element_type=jnp.float32,
+                       precision=_F32) * sm_scale
         ok = (kp >= 0) & (kp <= cur)
         if window:
             ok &= kp > cur - window
@@ -141,10 +190,10 @@ def ref_sparq_decode_attn(q, k_data, k_meta, k_scale, v_data, v_meta,
         p = jnp.exp(s - m_safe)
         p = jnp.where(okb, p, 0.0)
         corr = jnp.where(jnp.isneginf(m), 0.0, jnp.exp(m - m_safe))
-        l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+        l_new = l * corr + row_sum(p)
         v = _decode(vd, vm, v_scale)
         pv = jnp.einsum("bkgs,bskh->bkgh", p, v,
-                        preferred_element_type=jnp.float32)
+                        preferred_element_type=jnp.float32, precision=_F32)
         return (m_new, l_new, acc * corr + pv), None
 
     m0 = jnp.full((B, KV, G, 1), -jnp.inf, jnp.float32)
@@ -190,7 +239,8 @@ def ref_sparq_chunked_prefill_attn(q, k_chunk, v_chunk, k_data, k_meta,
 
     q           [C, KV, G, hd] float — chunk queries, GQA via grouping
     k/v_chunk   [C, KV, hd] float — the chunk's own (pre-quantization) K/V
-    k/v planes  [P, ps, KV, hd] int8 — the global §5.1 page pools
+    k/v planes  [P, ps, KV*hd] int8 — the global §5.1 page pools
+                (lane-dense: KV and hd flattened into one axis)
     k/v scale   [S] f32 — per-slot site scales (frozen at first write)
     block_table [S, NB] int32 — physical page per logical block (-1 unset)
     seq_id      [C] int32 — sequence slot per stream token (-1 = padding)
@@ -202,6 +252,7 @@ def ref_sparq_chunked_prefill_attn(q, k_chunk, v_chunk, k_data, k_meta,
                 aligned to bq so one tile gathers one block-table row
     Returns f32 [C, KV, G, hd]; fully-masked (padding) rows are zeros.
     """
+    k_scale, v_scale = _as_read(k_scale, v_scale)
     C, KV, G, hd = q.shape
     ps = k_data.shape[1]
     NB = block_table.shape[1]
@@ -231,25 +282,27 @@ def ref_sparq_chunked_prefill_attn(q, k_chunk, v_chunk, k_data, k_meta,
         p = jnp.exp(s - m_safe)
         p = jnp.where(okb, p, 0.0)
         corr = jnp.where(jnp.isneginf(m), 0.0, jnp.exp(m - m_safe))
-        l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+        l_new = l * corr + row_sum(p)
         return m_new, l_new, corr, p
 
     def tile(carry, t):
         m, l, acc = carry
         pages = block_table[s_safe, t]             # [C]
         pg = jnp.maximum(pages, 0)
-        k = _meta_decode32(k_data[pg], k_meta[pg],
+        k = _meta_decode32(_heads(KV, k_data[pg]), _heads(KV, k_meta[pg]),
                            ksc[:, None, None, None])   # [C, ps, KV, hd]
         s = jnp.einsum("ckgh,cskh->ckgs", qf, k,
-                       preferred_element_type=jnp.float32) * sm_scale
+                       preferred_element_type=jnp.float32,
+                       precision=_F32) * sm_scale
         kp = t * ps + jnp.arange(ps, dtype=jnp.int32)[None]    # [1, ps]
         ok = (pages >= 0)[:, None] & qvalid[:, None] & (kp < qhist[:, None])
         if window:
             ok &= kp > qpos[:, None] - window
         m, l, corr, p = upd(m, l, s, ok)
-        v = _meta_decode32(v_data[pg], v_meta[pg], vsc[:, None, None, None])
+        v = _meta_decode32(_heads(KV, v_data[pg]), _heads(KV, v_meta[pg]),
+                           vsc[:, None, None, None])
         pv = jnp.einsum("ckgs,cskh->ckgh", p, v,
-                        preferred_element_type=jnp.float32)
+                        preferred_element_type=jnp.float32, precision=_F32)
         return (m, l, acc * corr + pv), None
 
     m0 = jnp.full((C, KV, G, 1), -jnp.inf, jnp.float32)
@@ -257,20 +310,40 @@ def ref_sparq_chunked_prefill_attn(q, k_chunk, v_chunk, k_data, k_meta,
     a0 = jnp.zeros((C, KV, G, hd), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(tile, (m0, l0, a0), jnp.arange(NB))
 
-    # in-chunk causal stage: float K/V, segment mask by sequence id
+    # in-chunk causal stage: float K/V, segment mask by sequence id, in
+    # key tiles of chunk_key_tile(C), each one flash update. The
+    # contractions run per head as 2-D dots of the kernel's shapes
+    # ([C*G, hd] x [kt, hd]^T, then [C*G, kt] x [kt, hd]): XLA:CPU sums a
+    # 2-D f32 dot in a blocked order but a batched dot_general as one
+    # sequential FMA chain, so only matching dot shapes keeps this
+    # stage bit-identical to the interpret-mode kernel; on a TPU a tile
+    # of at most 128 keys is one MXU pass, which Mosaic and XLA sum
+    # alike. This copies the kernel's summation structure, so the oracle
+    # is not an independent formulation here: test_prefill's dense float
+    # oracle is the independent witness. It is also why flash-attention
+    # prefill (the batched form) and the chunk path differ by an ulp on
+    # XLA:CPU, which keeps test_write_chunk_bytes_match_adopt_prefill red.
     kcf = k_chunk.astype(jnp.float32)
     vcf = v_chunk.astype(jnp.float32)
-    s = jnp.einsum("ckgh,jkh->ckgj", qf, kcf,
-                   preferred_element_type=jnp.float32) * sm_scale
+    dot = functools.partial(jax.lax.dot_general, precision=_F32,
+                            preferred_element_type=jnp.float32)
+    per_head = lambda f: jnp.stack([f(h) for h in range(KV)], axis=1)
     ok = (sid[None, :] == sid[:, None]) & qvalid[:, None] \
         & (qpos[None, :] <= qpos[:, None]) \
         & (qpos[None, :] >= qhist[:, None])
     if window:
         ok &= qpos[None, :] > qpos[:, None] - window
-    m, l, corr, p = upd(m, l, s, ok)
-    pv = jnp.einsum("ckgj,jkh->ckgh", p, vcf,
-                    preferred_element_type=jnp.float32)
-    acc = acc * corr + pv
+    kt = chunk_key_tile(C)
+    for j in range(0, C, kt):
+        keys = slice(j, j + kt)
+        s = per_head(lambda h: dot(
+            qf[:, h].reshape(C * G, hd), kcf[keys, h],
+            (((1,), (1,)), ((), ()))).reshape(C, G, kt)) * sm_scale
+        m, l, corr, p = upd(m, l, s, ok[:, keys])
+        pv = per_head(lambda h: dot(
+            p[:, h].reshape(C * G, kt), vcf[keys, h],
+            (((1,), (0,)), ((), ()))).reshape(C, G, hd))
+        acc = acc * corr + pv
     return acc / jnp.maximum(l, 1e-30)
 
 
@@ -285,8 +358,9 @@ def ref_sparq_paged_decode_attn(q, k_data, k_meta, k_scale, v_data, v_meta,
     bytes the two paths agree bit for bit.
 
     q           [B, KV, G, hd] float — one query token per sequence
-    k/v planes  [P, ps, KV, hd] int8 — the global page pool (any page the
-                block table never names, e.g. a trash page, is simply dead)
+    k/v planes  [P, ps, KV*hd] int8 — the global lane-dense page pool (any
+                page the block table never names, e.g. a trash page, is
+                simply dead)
     k/v scale   [B] f32 — per-sequence site scales
     block_table [B, NB] int32 — physical page per logical block (-1 = not
                 allocated; masked out, gather index clamped to 0)
@@ -294,6 +368,7 @@ def ref_sparq_paged_decode_attn(q, k_data, k_meta, k_scale, v_data, v_meta,
                 (-1/-2 = inactive slot: fully masked, output 0)
     Returns f32 [B, KV, G, hd].
     """
+    k_scale, v_scale = _as_read(k_scale, v_scale)
     B, KV, G, hd = q.shape
     ps = k_data.shape[1]
     NB = block_table.shape[1]
@@ -307,9 +382,11 @@ def ref_sparq_paged_decode_attn(q, k_data, k_meta, k_scale, v_data, v_meta,
         m, l, acc = carry
         pages = jax.lax.dynamic_slice_in_dim(block_table, t, 1, 1)[:, 0]
         safe = jnp.maximum(pages, 0)                   # [B]
-        k = _meta_decode32(k_data[safe], k_meta[safe], k_scale)
+        k = _meta_decode32(_heads(KV, k_data[safe]),
+                           _heads(KV, k_meta[safe]), k_scale)
         s = jnp.einsum("bkgh,bskh->bkgs", qf, k,
-                       preferred_element_type=jnp.float32) * sm_scale
+                       preferred_element_type=jnp.float32,
+                       precision=_F32) * sm_scale
         kp = t * ps + jnp.arange(ps, dtype=jnp.int32)[None]    # [1, ps]
         ok = (pages >= 0)[:, None] & (kp <= cur_b)
         if window:
@@ -321,10 +398,11 @@ def ref_sparq_paged_decode_attn(q, k_data, k_meta, k_scale, v_data, v_meta,
         p = jnp.exp(s - m_safe)
         p = jnp.where(okb, p, 0.0)
         corr = jnp.where(jnp.isneginf(m), 0.0, jnp.exp(m - m_safe))
-        l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        v = _meta_decode32(v_data[safe], v_meta[safe], v_scale)
+        l_new = l * corr + row_sum(p)
+        v = _meta_decode32(_heads(KV, v_data[safe]),
+                           _heads(KV, v_meta[safe]), v_scale)
         pv = jnp.einsum("bkgs,bskh->bkgh", p, v,
-                        preferred_element_type=jnp.float32)
+                        preferred_element_type=jnp.float32, precision=_F32)
         return (m_new, l_new, acc * corr + pv), None
 
     m0 = jnp.full((B, KV, G, 1), -jnp.inf, jnp.float32)

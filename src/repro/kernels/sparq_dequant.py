@@ -11,18 +11,20 @@ reconstructs the SPARQ integer codes:
 
 This is the §5.1 decode datapath the paper's memory-footprint argument
 rests on — the cache holds (n + 3 + ½)-bit values, the MXU consumes 8-bit
-reconstructions. Grid is 1-D over row tiles; lane axis is the pair axis.
+reconstructions. Grid is 1-D over row tiles of `row_block(K)` rows; lane
+axis is the pair axis.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels.sparq_quant import row_block
 
 
 def _kernel(store_ref, meta_ref, codes_ref):
@@ -39,11 +41,12 @@ def sparq_dequant_pallas(
     store: jnp.ndarray,       # (M, K) int8 window codes
     meta: jnp.ndarray,        # (M, K) int8 packed ShiftCtrl/MuxCtrl bytes
     *,
-    bm: int = 256,
+    bm: Optional[int] = None,
     interpret: bool = False,
 ):
     """Returns int8 (M, K): SPARQ-reconstructed integer codes."""
     M, K = store.shape
+    bm = bm or row_block(K)
     assert store.shape == meta.shape, (store.shape, meta.shape)
     assert M % bm == 0 and K % 2 == 0, (M, K, bm)
     return pl.pallas_call(
@@ -55,7 +58,7 @@ def sparq_dequant_pallas(
         ],
         out_specs=pl.BlockSpec((bm, K), lambda m: (m, 0)),
         out_shape=jax.ShapeDtypeStruct((M, K), jnp.int8),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(store, meta)

@@ -7,7 +7,9 @@ VMEM-resident tiles, immediately before the MXU contraction, so the
 activation tensor is read from HBM exactly once and SPARQ costs no extra
 memory traffic. Products accumulate in an int32 VMEM scratch (the psum
 register of the paper's PE); per-output-channel weight scales and the
-per-tensor activation scale are applied once on the final K step.
+per-tensor activation scale are applied once on the final K step. Signed
+codes (clipped to ±127) feed the MXU as int8 operands; the paper's
+unsigned mode (codes up to 255) takes an exact bf16 dot per K tile.
 
 vSPARQ pairing is implemented with a lane roll instead of a reshape:
 partner(i) = x[i+1] for even lanes, x[i-1] for odd lanes — a pure
@@ -31,9 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels._compat import CompilerParams as _CompilerParams
-from repro.kernels._compat import MemorySpace as _MemorySpace
 
 from repro.core.bsparq import bsparq_recon
 
@@ -78,15 +77,27 @@ def _kernel(x_ref, w_ref, ascale_ref, cscale_ref, o_ref, acc_ref, *,
     if enabled:
         q = _recon_tile(q, bits=bits, shifts=shifts, rounding=rounding,
                         vsparq=vsparq, signed=signed, max_val=max_val)
-    w = w_ref[...].astype(jnp.int32)
-    acc_ref[...] += jax.lax.dot_general(
-        q, w, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
+    dims = (((1,), (0,)), ((), ()))
+    if signed and max_val <= 127:
+        # codes fit int8: the native int8 x int8 -> int32 MXU dot
+        acc_ref[...] += jax.lax.dot_general(
+            q.astype(jnp.int8), w_ref[...], dimension_numbers=dims,
+            preferred_element_type=jnp.int32)
+    else:
+        # unsigned codes reach 255 and do not fit int8. A bf16 dot with an
+        # f32 accumulator is exact inside one K tile: every code and
+        # weight fits bf16's 8-bit significand, and a tile's partial sums
+        # stay below 2^24 (bk * 255 * 128 < 2^24 for bk <= 512)
+        acc_ref[...] += jax.lax.dot_general(
+            q.astype(jnp.bfloat16), w_ref[...].astype(jnp.bfloat16),
+            dimension_numbers=dims,
+            preferred_element_type=jnp.float32).astype(jnp.int32)
 
     @pl.when(k == pl.num_programs(2) - 1)
     def _emit():
-        o_ref[...] = (acc_ref[...].astype(jnp.float32) * a *
-                      cscale_ref[...].astype(jnp.float32))
+        # one combined scale per column, as in ref_sparq_matmul
+        o_ref[...] = (acc_ref[...].astype(jnp.float32) *
+                      (a * cscale_ref[...].astype(jnp.float32)))
 
 
 @functools.partial(
@@ -117,6 +128,8 @@ def sparq_matmul_pallas(
     assert M % bm == 0 and N % bn == 0 and K % bk == 0, \
         f"pad to tiles first: {(M, K, N)} vs {(bm, bk, bn)}"
     assert bk % 2 == 0, "K tile must be even (vSPARQ pairs adjacent lanes)"
+    assert (signed and max_val <= 127) or bk <= 512, \
+        f"unsigned codes accumulate exactly in f32 only for bk <= 512: {bk}"
 
     grid = (M // bm, N // bn, K // bk)
     kernel = functools.partial(
@@ -129,13 +142,13 @@ def sparq_matmul_pallas(
             pl.BlockSpec((bm, bk), lambda m, n, k: (m, k)),
             pl.BlockSpec((bk, bn), lambda m, n, k: (k, n)),
             pl.BlockSpec((1, 1), lambda m, n, k: (0, 0),
-                         memory_space=_MemorySpace.SMEM),
+                         memory_space=pltpu.MemorySpace.SMEM),
             pl.BlockSpec((1, bn), lambda m, n, k: (0, n)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w_codes, act_scale.reshape(1, 1), chan_scale.reshape(1, N))

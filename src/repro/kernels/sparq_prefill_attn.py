@@ -25,24 +25,30 @@ resume bit-exact) under any join pattern, pool size, or preemption
 schedule.
 
 Shapes and grid:
-  q          [C, KV, G, hd]  chunk queries, GQA via head grouping
-  k/v_chunk  [C, KV, hd]     the chunk's own float K/V
-  k/v pools  [P, ps, KV, hd] int8 §5.1 planes (global page pool)
-  seq_id/pos [1, C]          per-token stream metadata (-1 = padding)
-  hist       [1, C]          per-token history boundary (pages < hist)
+  q          [KV, C*G, hd]   chunk queries, one row per (token, group)
+  q_seq/pos/hist [C*G, 1]    per-row stream metadata (-1 = padding)
+  k_seq/pos  [1, C]          per-token stream metadata of the keys
+  k/v_chunk  [C, KV*hd]      the chunk's own float K/V, lane-dense
+  k/v pools  [P, ps, KV*hd]  int8 §5.1 planes (global page pool)
   tile_seq   [nt]            slot owning each bq-aligned query tile
 
-grid = (C/bq, KV, NB + 1): stages 0..NB-1 stream the tile's sequence's
-pages (ascending kpos), stage NB is the in-chunk causal stage; the stage
+grid = (C/bq, NB + 1): stages 0..NB-1 stream the tile's sequence's
+pages (ascending kpos), stage NB is the in-chunk causal stage, one
+flash update per 128 chunk keys (`ref.chunk_key_tile`); the stage
 axis is sequential ("arbitrary") and carries the flash statistics
-(m, l, acc) in VMEM scratch, with one row per (token, group) pair. The
-stage order and f32 update arithmetic mirror
+(m, l, acc) in VMEM scratch, one row per (token, group) pair and one
+slab per local KV head. Every block covers whole minor dims (all local
+heads of a page, every group of a token), so the TPU tiling rule holds
+at any head count; the head loop runs inside the kernel (see
+`sparq_decode_attn` for the lane-dense plane layout). The stage order
+and f32 update arithmetic (`sparq_decode_attn.flash_update`) mirror
 `kernels.ref.ref_sparq_chunked_prefill_attn` op for op. Interpret-mode
-outputs agree with the oracle to within a couple of f32 ulps (XLA fuses
-the oracle's scanned multiply-add chain differently from the
-interpreter's op-by-op execution); the in-chunk stage alone is exact,
-and each engine run uses one impl throughout, so the serving-level
-greedy-token-equality guarantees are unaffected.
+outputs agree with the oracle bit for bit in the in-chunk stage, where
+the oracle contracts with the kernel's per-head 2-D dot shapes, and to
+within a couple of f32 ulps over page tiles, where it gathers pages per
+token and contracts with batched dots (XLA:CPU sums the two dot forms
+in different orders); each engine run uses one impl throughout, so the
+serving-level greedy-token guarantees are unaffected.
 """
 from __future__ import annotations
 
@@ -53,8 +59,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-from repro.kernels.sparq_decode_attn import _meta_decode_f32
+from repro.kernels.ref import chunk_key_tile
+from repro.kernels.sparq_decode_attn import (decode_head, flash_update,
+                                             init_stats, stats_scratch)
 
 
 def _kernel(tile_seq_ref, bt_ref, ks_ref, vs_ref,          # scalar pref.
@@ -63,74 +70,51 @@ def _kernel(tile_seq_ref, bt_ref, ks_ref, vs_ref,          # scalar pref.
             o_ref, m_ref, l_ref, acc_ref, *,
             window: int, sm_scale: float, ps: int, nb: int):
     qt = pl.program_id(0)
-    t = pl.program_id(2)
+    t = pl.program_id(1)
+    n_kv, _, hd = acc_ref.shape
 
     @pl.when(t == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        init_stats(m_ref, l_ref, acc_ref)
 
     s_tile = jnp.maximum(tile_seq_ref[qt], 0)
-    q = q_ref[:, 0].astype(jnp.float32)                # [bq, G, hd]
-    bq, G, hd = q.shape
-    q2 = q.reshape(bq * G, hd)
-    qseq = qseq_ref[0]                                 # [bq]
-    qpos = qpos_ref[0]
-    qhist = qhist_ref[0]
+    qseq = qseq_ref[...]                               # [bq*G, 1]
+    qpos = qpos_ref[...]
+    qhist = qhist_ref[...]
     qvalid = qseq >= 0
-
-    def update(k, v, ok):
-        """One online-softmax tile update on [bq*G] rows; the mask `ok`
-        is per (token, key) and fans out over the G group rows. Identical
-        op order to the oracle's `upd` (and the decode kernels')."""
-        ok2 = jnp.repeat(ok, G, axis=0)                # [bq*G, keys]
-        s = jax.lax.dot_general(
-            q2, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        s = jnp.where(ok2, s, -jnp.inf)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
-        p = jnp.exp(s - m_safe)
-        p = jnp.where(ok2, p, 0.0)
-        corr = jnp.where(jnp.isneginf(m_prev), 0.0,
-                         jnp.exp(m_prev - m_safe))
-        l_new = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
-        l_ref[...] = l_new
-        acc_ref[...] = acc_ref[...] * corr + pv
 
     @pl.when(t < nb)
     def _page_stage():
-        k = _meta_decode_f32(kd_ref[0, :, 0], km_ref[0, :, 0],
-                             ks_ref[s_tile])           # [ps, hd]
-        v = _meta_decode_f32(vd_ref[0, :, 0], vm_ref[0, :, 0],
-                             vs_ref[s_tile])
         kp = t * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-        ok = (bt_ref[s_tile, t] >= 0) & qvalid[:, None] \
-            & (kp < qhist[:, None])                    # [bq, ps]
+        ok = (bt_ref[s_tile, t] >= 0) & qvalid & (kp < qhist)  # [bq*G, ps]
         if window:
-            ok &= kp > qpos[:, None] - window
-        update(k, v, ok)
+            ok &= kp > qpos - window
+        for h in range(n_kv):
+            k = decode_head(kd_ref, km_ref, h, hd, ks_ref[s_tile])
+            v = decode_head(vd_ref, vm_ref, h, hd, vs_ref[s_tile])
+            flash_update(q_ref[h].astype(jnp.float32), k, v, ok,
+                         m_ref, l_ref, acc_ref, h, sm_scale=sm_scale)
 
     @pl.when(t == nb)
     def _chunk_stage():
-        k = kc_ref[:, 0].astype(jnp.float32)           # [C, hd]
-        v = vc_ref[:, 0].astype(jnp.float32)
-        kseq = kseq_ref[0]                             # [C]
-        kpos = kpos_ref[0]
-        ok = (kseq[None, :] == qseq[:, None]) & qvalid[:, None] \
-            & (kpos[None, :] <= qpos[:, None]) \
-            & (kpos[None, :] >= qhist[:, None])        # [bq, C]
+        kseq = kseq_ref[...]                           # [1, C]
+        kpos = kpos_ref[...]
+        ok = (kseq == qseq) & qvalid & (kpos <= qpos) \
+            & (kpos >= qhist)                          # [bq*G, C]
         if window:
-            ok &= kpos[None, :] > qpos[:, None] - window
-        update(k, v, ok)
-        o_ref[:, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)).reshape(bq, G, hd)
+            ok &= kpos > qpos - window
+        C = kc_ref.shape[0]
+        kt = chunk_key_tile(C)
+        for h in range(n_kv):
+            lanes = pl.ds(h * hd, hd)
+            for j in range(0, C, kt):                  # key tiles
+                keys = pl.ds(j, kt)
+                k = kc_ref[keys, lanes].astype(jnp.float32)   # [kt, hd]
+                v = vc_ref[keys, lanes].astype(jnp.float32)
+                flash_update(q_ref[h].astype(jnp.float32), k, v,
+                             ok[:, j:j + kt], m_ref, l_ref, acc_ref, h,
+                             sm_scale=sm_scale)
+            o_ref[h] = acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)
 
 
 @functools.partial(jax.jit,
@@ -139,8 +123,8 @@ def sparq_chunked_prefill_attn_pallas(
     q: jnp.ndarray,            # (C, KV, G, hd) float — chunk queries
     k_chunk: jnp.ndarray,      # (C, KV, hd) float — chunk K (pre-quant)
     v_chunk: jnp.ndarray,      # (C, KV, hd) float
-    k_data: jnp.ndarray,       # (P, ps, KV, hd) int8 window-code pool
-    k_meta: jnp.ndarray,       # (P, ps, KV, hd) int8 meta-byte pool
+    k_data: jnp.ndarray,       # (P, ps, KV*hd) int8 window-code pool
+    k_meta: jnp.ndarray,       # (P, ps, KV*hd) int8 meta-byte pool
     k_scale: jnp.ndarray,      # (S,) f32 per-slot site scales
     v_data: jnp.ndarray,
     v_meta: jnp.ndarray,
@@ -159,52 +143,46 @@ def sparq_chunked_prefill_attn_pallas(
     C, KV, G, hd = q.shape
     P, ps = k_data.shape[:2]
     NB = block_table.shape[1]
+    assert k_data.shape == (P, ps, KV * hd), (q.shape, k_data.shape)
     assert C % bq == 0 and hd % 2 == 0, (C, bq, hd)
     assert tile_seq.shape == (C // bq,), tile_seq.shape
     kernel = functools.partial(_kernel, window=window,
                                sm_scale=hd ** -0.5, ps=ps, nb=NB)
-    seq2d = seq_id.astype(jnp.int32).reshape(1, C)
-    pos2d = pos.astype(jnp.int32).reshape(1, C)
-    hist2d = hist.astype(jnp.int32).reshape(1, C)
+    # queries and their metadata one row per (token, group) pair, so the
+    # masks build at score-row granularity with no in-kernel repeat
+    rows = lambda x: jnp.repeat(x.astype(jnp.int32), G).reshape(C * G, 1)
+    cols = lambda x: x.astype(jnp.int32).reshape(1, C)
+    qh = q.transpose(1, 0, 2, 3).reshape(KV, C * G, hd)
 
-    def page_idx(qt, kv, t, ts, bt, ks, vs):
+    def page_idx(qt, t, ts, bt, ks, vs):
         # stage t streams the tile's sequence's page t; the chunk stage
         # (t == NB) and unallocated blocks clamp to page 0 (masked out)
         s = jnp.maximum(ts[qt], 0)
-        return (jnp.maximum(bt[s, jnp.minimum(t, NB - 1)], 0), 0, kv, 0)
+        return (jnp.maximum(bt[s, jnp.minimum(t, NB - 1)], 0), 0, 0)
 
-    plane = pl.BlockSpec((1, ps, 1, hd), page_idx)
+    plane = pl.BlockSpec((1, ps, KV * hd), page_idx)
+    row_meta = pl.BlockSpec((bq * G, 1), lambda qt, t, *s: (qt, 0))
+    key_meta = pl.BlockSpec((1, C), lambda qt, t, *s: (0, 0))
+    chunk_kv = pl.BlockSpec((C, KV * hd), lambda qt, t, *s: (0, 0))
+    heads = pl.BlockSpec((KV, bq * G, hd), lambda qt, t, *s: (0, qt, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,  # tile_seq, block_table, k/v scales
-        grid=(C // bq, KV, NB + 1),
-        in_specs=[
-            pl.BlockSpec((bq, 1, G, hd),
-                         lambda qt, kv, t, *s: (qt, kv, 0, 0)),
-            pl.BlockSpec((1, bq), lambda qt, kv, t, *s: (0, qt)),
-            pl.BlockSpec((1, bq), lambda qt, kv, t, *s: (0, qt)),
-            pl.BlockSpec((1, bq), lambda qt, kv, t, *s: (0, qt)),
-            pl.BlockSpec((1, C), lambda qt, kv, t, *s: (0, 0)),
-            pl.BlockSpec((1, C), lambda qt, kv, t, *s: (0, 0)),
-            pl.BlockSpec((C, 1, hd), lambda qt, kv, t, *s: (0, kv, 0)),
-            pl.BlockSpec((C, 1, hd), lambda qt, kv, t, *s: (0, kv, 0)),
-            plane, plane, plane, plane,
-        ],
-        out_specs=pl.BlockSpec((bq, 1, G, hd),
-                               lambda qt, kv, t, *s: (qt, kv, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((bq * G, 1), jnp.float32),   # m: running max
-            pltpu.VMEM((bq * G, 1), jnp.float32),   # l: running denom
-            pltpu.VMEM((bq * G, hd), jnp.float32),  # acc: running numer
-        ],
+        grid=(C // bq, NB + 1),
+        in_specs=[heads, row_meta, row_meta, row_meta, key_meta, key_meta,
+                  chunk_kv, chunk_kv, plane, plane, plane, plane],
+        out_specs=heads,
+        scratch_shapes=stats_scratch(KV, bq * G, hd),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((C, KV, G, hd), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        out_shape=jax.ShapeDtypeStruct((KV, C * G, hd), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tile_seq.astype(jnp.int32), block_table.astype(jnp.int32),
       k_scale.astype(jnp.float32), v_scale.astype(jnp.float32),
-      q, seq2d, pos2d, hist2d, seq2d, pos2d, k_chunk, v_chunk,
+      qh, rows(seq_id), rows(pos), rows(hist), cols(seq_id), cols(pos),
+      k_chunk.reshape(C, KV * hd), v_chunk.reshape(C, KV * hd),
       k_data, k_meta, v_data, v_meta)
+    return out.reshape(KV, C, G, hd).transpose(1, 0, 2, 3)

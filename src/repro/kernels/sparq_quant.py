@@ -9,21 +9,30 @@ codes unpacked int8 here because the MXU consumes 8-bit operands anyway
 (the packed format only matters for HBM residency, which `bytes_per_value`
 in ops.py models for the roofline analysis).
 
-Grid is 1-D over row tiles; the lane (last) axis is the pairing axis.
+Grid is 1-D over row tiles of `row_block(K)` rows; the lane (last) axis
+is the pairing axis.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-from repro.kernels._compat import MemorySpace as _MemorySpace
-
 from repro.core.bsparq import bsparq_encode
+
+
+def row_block(K: int) -> int:
+    """Row tile of the row-streaming kernels (`sparq_quant`,
+    `sparq_dequant`), whose block is a whole (bm, K) row slab. Sized so
+    the slab is at most 2^18 elements (1 MiB of f32 input): the quant
+    kernel's int32 temporaries then fit the v5e's scoped VMEM at every K
+    the main path passes, where a fixed 256 rows ran out at K = 5632 (the
+    TinyLlama FFN width). A multiple of 32 rows, the int8 sublane tile."""
+    return min(256, max(32, (1 << 18) // K // 32 * 32))
 
 
 def _kernel(x_ref, ascale_ref, codes_ref, meta_ref, *,
@@ -87,12 +96,13 @@ def sparq_quant_pallas(
     signed: bool = True,
     max_val: int = 127,
     enabled: bool = True,
-    bm: int = 256,
+    bm: Optional[int] = None,
     interpret: bool = False,
 ):
     """Returns (codes int8 [M,K] — SPARQ-reconstructed integer values,
     meta int8 [M,K] — per-lane packed ShiftCtrl/MuxCtrl byte)."""
     M, K = x.shape
+    bm = bm or row_block(K)
     assert M % bm == 0 and K % 2 == 0, (M, K, bm)
     kernel = functools.partial(
         _kernel, bits=bits, shifts=opts_shifts, rounding=rounding,
@@ -103,7 +113,7 @@ def sparq_quant_pallas(
         in_specs=[
             pl.BlockSpec((bm, K), lambda m: (m, 0)),
             pl.BlockSpec((1, 1), lambda m: (0, 0),
-                         memory_space=_MemorySpace.SMEM),
+                         memory_space=pltpu.MemorySpace.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((bm, K), lambda m: (m, 0)),
@@ -113,7 +123,7 @@ def sparq_quant_pallas(
             jax.ShapeDtypeStruct((M, K), jnp.int8),
             jax.ShapeDtypeStruct((M, K), jnp.int8),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, act_scale.reshape(1, 1))

@@ -10,18 +10,27 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape, axes):
+    """`jax.make_mesh` with Auto axes: the sharding constraints and
+    shard_map specs of this repo name bare PartitionSpecs and leave
+    propagation to the compiler, which Explicit axes (the make_mesh
+    default) refuse."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model_parallel: int = 1):
     """Small mesh over whatever devices exist (tests / local runs)."""
     n = len(jax.devices())
     assert n % model_parallel == 0
-    return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
+    return _auto_mesh((n // model_parallel, model_parallel),
+                      ("data", "model"))
 
 
 def make_tp_mesh(tp: int):

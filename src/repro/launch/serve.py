@@ -56,6 +56,7 @@ import numpy as np
 from repro.configs.base import get_config, get_reduced_config
 from repro.core.sparq import SparqConfig
 from repro.data.pipeline import Batcher, DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import cache as cache_mod
 from repro.models import paging
 from repro.models.cache import CacheConfig
@@ -445,6 +446,8 @@ class ContinuousBatchingEngine:
                 f"--tp {self.tp} must divide n_kv_heads="
                 f"{model.cfg.n_kv_heads}: the packed (data, meta) planes "
                 f"shard by whole GQA head groups")
+        if mesh is not None and ctx is not None:
+            ctx = dataclasses.replace(ctx, mesh=mesh)
         self.model = model
         self.cc = cache_cfg
         self.ctx = ctx
@@ -1829,7 +1832,7 @@ class ContinuousBatchingEngine:
         return results, stats
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
     ap.add_argument("--reduced", action="store_true")
@@ -1947,35 +1950,77 @@ def main(argv=None):
                     help="skip the untimed warmup pass (timings then "
                          "include XLA compilation)")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap
 
+
+def load_model(args):
+    """The model the flags name, with seeded weights (nothing is
+    downloaded), its prompt batcher, and the calibrated activation scales
+    of the `--sparq` preset. Returns (model, params, data, scales)."""
     cfg = get_reduced_config(args.arch) if args.reduced \
         else get_config(args.arch)
     model = Model(cfg)
     params = model.init_params(jax.random.PRNGKey(args.seed))
-
     data = Batcher(DataConfig(
         vocab_size=cfg.vocab_size, seq_len=args.prompt_len,
         global_batch=args.batch, seed=args.seed, frontend=cfg.frontend,
         frontend_len=cfg.frontend_len, d_model=cfg.d_model))
-    batch = data.global_batch(0)
-    batch.pop("labels", None)
-
     scfg = SPARQ_PRESETS[args.sparq]
-    ctx, scales = None, None
+    scales = None
     if scfg is not None:
         scales = model.calibrate(params, data.calib_batches(args.calibrate)) \
             if args.calibrate else None
-        ctx = QuantCtx(mode="quantized", cfg=scfg, impl=args.impl)
         if args.prequantize:
             from repro.models.quantize import quantize_params
             params = quantize_params(params, scfg.weight_bits)
+    return model, params, data, scales
 
-    cache_cfg = make_cache_config(args.kv_cache, scfg, args.impl)
-    print(f"arch={cfg.name} sparq={args.sparq} kv-cache={args.kv_cache} "
-          f"impl={args.impl} engine={args.engine} batch={args.batch} "
-          f"prompt={args.prompt_len} gen={args.gen}")
 
+def quant_ctx(args) -> Optional[QuantCtx]:
+    """The quantized-matmul context of `--sparq` / `--impl`."""
+    scfg = SPARQ_PRESETS[args.sparq]
+    return None if scfg is None else QuantCtx(mode="quantized", cfg=scfg,
+                                              impl=args.impl)
+
+
+def paged_engine(args, model: Model, scales) -> "ContinuousBatchingEngine":
+    """The `--engine paged` engine the flags describe: pool sized for
+    `--prompt-len + --gen` per sequence, preemption policy, TP mesh and
+    telemetry level."""
+    need = args.prompt_len + args.gen - 1
+    max_seq = -(-need // args.page_size) * args.page_size
+    pages_per_seq = max_seq // args.page_size
+    n_pages = args.n_pages
+    if args.oversubscribe:
+        n_pages = max(pages_per_seq,
+                      math.ceil(args.oversubscribe * args.batch
+                                * pages_per_seq))
+    policy = None if args.preempt == "off" else SchedulerPolicy(
+        preempt=args.preempt, victim=args.victim)
+    mesh = None
+    if args.tp > 1:
+        from repro.launch.mesh import make_tp_mesh
+        mesh = make_tp_mesh(args.tp)
+    telemetry = Telemetry.tracing() if args.trace_out else Telemetry()
+    cache_cfg = make_cache_config(args.kv_cache, SPARQ_PRESETS[args.sparq],
+                                  args.impl)
+    return ContinuousBatchingEngine(
+        model, cache_cfg, quant_ctx(args), scales,
+        page_size=args.page_size, n_pages=n_pages,
+        max_active=args.max_active or args.batch,
+        max_seq_len=max_seq, policy=policy,
+        prefill=args.prefill, chunk_size=args.chunk_size,
+        chunk_align=args.chunk_align,
+        chunk_seg=args.chunk_seg or None,
+        prefix_cache=args.prefix_cache,
+        prefix_min_pages=args.prefix_min_pages,
+        prefill_priority=args.prefill_priority,
+        mesh=mesh, telemetry=telemetry)
+
+
+def main(argv=None):
+    ap = build_parser()
+    args = ap.parse_args(argv)
     if args.serve == "async" and args.engine != "paged":
         ap.error("--serve async streams from the paged engine's decode "
                  "loop; add --engine paged")
@@ -1994,37 +2039,20 @@ def main(argv=None):
             ap.error("--prefix-cache relies on the chunked path's "
                      "scheduling-invariant packed bytes; add "
                      "--prefill chunked")
-        need = args.prompt_len + args.gen - 1
-        max_seq = -(-need // args.page_size) * args.page_size
-        pages_per_seq = max_seq // args.page_size
-        n_pages = args.n_pages
-        if args.oversubscribe:
-            if args.preempt == "off":
-                ap.error("--oversubscribe deliberately undersizes the "
-                         "pool; pick --preempt requeue|swap so the engine "
-                         "can evict victims instead of raising")
-            n_pages = max(pages_per_seq,
-                          math.ceil(args.oversubscribe * args.batch
-                                    * pages_per_seq))
-        policy = None if args.preempt == "off" else SchedulerPolicy(
-            preempt=args.preempt, victim=args.victim)
-        mesh = None
-        if args.tp > 1:
-            from repro.launch.mesh import make_tp_mesh
-            mesh = make_tp_mesh(args.tp)
-        telemetry = Telemetry.tracing() if args.trace_out else Telemetry()
-        engine = ContinuousBatchingEngine(
-            model, cache_cfg, ctx, scales,
-            page_size=args.page_size, n_pages=n_pages,
-            max_active=args.max_active or args.batch,
-            max_seq_len=max_seq, policy=policy,
-            prefill=args.prefill, chunk_size=args.chunk_size,
-            chunk_align=args.chunk_align,
-            chunk_seg=args.chunk_seg or None,
-            prefix_cache=args.prefix_cache,
-            prefix_min_pages=args.prefix_min_pages,
-            prefill_priority=args.prefill_priority,
-            mesh=mesh, telemetry=telemetry)
+        if args.oversubscribe and args.preempt == "off":
+            ap.error("--oversubscribe deliberately undersizes the "
+                     "pool; pick --preempt requeue|swap so the engine "
+                     "can evict victims instead of raising")
+    enable_compile_cache()
+    model, params, data, scales = load_model(args)
+    batch = data.global_batch(0)
+    batch.pop("labels", None)
+    print(f"arch={model.cfg.name} sparq={args.sparq} "
+          f"kv-cache={args.kv_cache} impl={args.impl} engine={args.engine} "
+          f"batch={args.batch} prompt={args.prompt_len} gen={args.gen}")
+
+    if args.engine == "paged":
+        engine = paged_engine(args, model, scales)
 
         def dump_telemetry():
             from repro.obs import export as obs_export
@@ -2083,7 +2111,7 @@ def main(argv=None):
                   f"{stats['prefix_hit_tokens']} prompt tokens from "
                   f"cache, {stats['prefix_shared_pages']} pages shared, "
                   f"{stats['cow_copies']} CoW copies")
-        if policy is not None:
+        if engine.policy is not None:
             print(f"preempt={args.preempt} victim={args.victim}: "
                   f"{stats['preemptions']} preemptions, "
                   f"{stats['resumes']} resumes, "
@@ -2093,8 +2121,10 @@ def main(argv=None):
         print("sample:", results[0][:16])
         return stats
 
-    toks, stats = serve(model, params, batch, args.gen, ctx, scales,
-                        cache_cfg, warmup=not args.no_warmup)
+    cache_cfg = make_cache_config(args.kv_cache, SPARQ_PRESETS[args.sparq],
+                                  args.impl)
+    toks, stats = serve(model, params, batch, args.gen, quant_ctx(args),
+                        scales, cache_cfg, warmup=not args.no_warmup)
     print(f"compile {stats['compile_s']:.1f} s | "
           f"prefill {stats['prefill_s']*1e3:.0f} ms | decode "
           f"{stats['decode_tok_s']:.1f} tok/s | cache "

@@ -114,64 +114,67 @@ def main(argv=None):
         if args.mesh == "production" else \
         make_host_mesh(args.model_parallel)
     shd.set_activation_spec(shd.activation_spec(mesh, sp=False), mesh=mesh)
+    try:
+        data = Batcher(DataConfig(
+            vocab_size=cfg.vocab_size, seq_len=args.seq,
+            global_batch=args.batch, seed=args.seed,
+            frontend=cfg.frontend, frontend_len=cfg.frontend_len,
+            d_model=cfg.d_model))
 
-    data = Batcher(DataConfig(
-        vocab_size=cfg.vocab_size, seq_len=args.seq,
-        global_batch=args.batch, seed=args.seed,
-        frontend=cfg.frontend, frontend_len=cfg.frontend_len,
-        d_model=cfg.d_model))
+        with mesh:
+            params = model.init_params(jax.random.PRNGKey(args.seed))
+            p_specs = shd.param_pspecs(params, mesh)
+            params = shard_tree(params, mesh, p_specs)
+            opt_state = opt.init(params)
+            comp_state = compressor.init(params) if compressor else None
 
-    with mesh:
-        params = model.init_params(jax.random.PRNGKey(args.seed))
-        p_specs = shd.param_pspecs(params, mesh)
-        params = shard_tree(params, mesh, p_specs)
-        opt_state = opt.init(params)
-        comp_state = compressor.init(params) if compressor else None
+            start_step = 0
+            if args.checkpoint_dir and args.restore:
+                step = ckpt.latest_step(args.checkpoint_dir)
+                if step is not None:
+                    shardings = jax.tree.map(
+                        lambda s: NamedSharding(mesh, s), p_specs,
+                        is_leaf=lambda x: isinstance(x, P))
+                    state = ckpt.restore(
+                        args.checkpoint_dir, step,
+                        {"params": params, "m": opt_state.m, "v": opt_state.v},
+                        {"params": shardings, "m": shardings, "v": shardings})
+                    params = state["params"]
+                    opt_state = opt_state._replace(
+                        m=state["m"], v=state["v"],
+                        count=jnp.asarray(step, jnp.int32))
+                    start_step = step
+                    print(f"restored step {step} from {args.checkpoint_dir}")
 
-        start_step = 0
-        if args.checkpoint_dir and args.restore:
-            step = ckpt.latest_step(args.checkpoint_dir)
-            if step is not None:
-                shardings = jax.tree.map(
-                    lambda s: NamedSharding(mesh, s), p_specs,
-                    is_leaf=lambda x: isinstance(x, P))
-                state = ckpt.restore(
-                    args.checkpoint_dir, step,
-                    {"params": params, "m": opt_state.m, "v": opt_state.v},
-                    {"params": shardings, "m": shardings, "v": shardings})
-                params = state["params"]
-                opt_state = opt_state._replace(
-                    m=state["m"], v=state["v"],
-                    count=jnp.asarray(step, jnp.int32))
-                start_step = step
-                print(f"restored step {step} from {args.checkpoint_dir}")
+            step_fn = jax.jit(build_train_step(model, opt, compressor),
+                              donate_argnums=(0, 1, 2))
+            coord = ElasticCoordinator(n_workers=jax.process_count())
 
-        step_fn = jax.jit(build_train_step(model, opt, compressor),
-                          donate_argnums=(0, 1, 2))
-        coord = ElasticCoordinator(n_workers=jax.process_count())
-
-        losses = []
-        for step in range(start_step, args.steps):
-            t0 = time.perf_counter()
-            batch = data.global_batch(step)
-            params, opt_state, comp_state, metrics = step_fn(
-                params, opt_state, comp_state, batch)
-            dt = time.perf_counter() - t0
-            coord.step_report(jax.process_index(), step, dt)
-            losses.append(float(metrics["loss"]))
-            if step % args.log_every == 0 or step == args.steps - 1:
-                print(f"step {step} loss {float(metrics['loss']):.4f} "
-                      f"gnorm {float(metrics['grad_norm']):.3f} "
-                      f"({dt*1000:.0f} ms)", flush=True)
-            if args.checkpoint_dir and \
-                    (step + 1) % args.checkpoint_every == 0:
-                ckpt.save(args.checkpoint_dir, step + 1,
-                          {"params": params, "m": opt_state.m,
-                           "v": opt_state.v})
-        if args.checkpoint_dir:
-            ckpt.save(args.checkpoint_dir, args.steps,
-                      {"params": params, "m": opt_state.m, "v": opt_state.v})
-    shd.set_activation_spec(None, None)
+            losses = []
+            for step in range(start_step, args.steps):
+                t0 = time.perf_counter()
+                batch = data.global_batch(step)
+                params, opt_state, comp_state, metrics = step_fn(
+                    params, opt_state, comp_state, batch)
+                dt = time.perf_counter() - t0
+                coord.step_report(jax.process_index(), step, dt)
+                losses.append(float(metrics["loss"]))
+                if step % args.log_every == 0 or step == args.steps - 1:
+                    print(f"step {step} loss {float(metrics['loss']):.4f} "
+                          f"gnorm {float(metrics['grad_norm']):.3f} "
+                          f"({dt*1000:.0f} ms)", flush=True)
+                if args.checkpoint_dir and \
+                        (step + 1) % args.checkpoint_every == 0:
+                    ckpt.save(args.checkpoint_dir, step + 1,
+                              {"params": params, "m": opt_state.m,
+                               "v": opt_state.v})
+            if args.checkpoint_dir:
+                ckpt.save(args.checkpoint_dir, args.steps,
+                          {"params": params, "m": opt_state.m, "v": opt_state.v})
+    finally:
+        # module-global state: reset it on failure too, or every later
+        # caller in this process inherits a dead mesh
+        shd.set_activation_spec(None, None)
     return losses
 
 

@@ -98,6 +98,7 @@ class QuantCtx:
     skip_sites: tuple[str, ...] = ()      # paper: first layer left intact
     site_prefix: str = ""                 # per-layer prefix (calibration)
     stc: bool = False                     # Sparse-TC path (2:4-pruned w)
+    mesh: Optional[Any] = None            # tensor-parallel jax Mesh
 
     @staticmethod
     def off() -> "QuantCtx":
@@ -138,7 +139,7 @@ def dense(w, x: jnp.ndarray, site: str,
             w_codes = quantize(w, w_qs).astype(jnp.int8)
             chan_scale = w_qs.scale
         out = quantized_matmul(x, w_codes, act_qs, chan_scale, cfg,
-                               impl=ctx.impl)
+                               impl=ctx.impl, mesh=ctx.mesh)
         return out.astype(x.dtype)
     raise ValueError(ctx.mode)
 
@@ -147,10 +148,23 @@ def dense(w, x: jnp.ndarray, site: str,
 # primitive layers
 # ----------------------------------------------------------------------
 
+def _sum_last(x: jnp.ndarray) -> jnp.ndarray:
+    """Sum over the last axis, keepdims, in one fixed order: halves added
+    elementwise while the width is even. A reduce lets XLA choose its
+    order per fusion, so the same norm compiled into two programs (the
+    Pallas and the reference serving path, TP=4 and TP=1) can differ by
+    an ulp, which the 8-bit activation quantizer turns into a code."""
+    while x.shape[-1] % 2 == 0 and x.shape[-1] > 1:
+        half = x.shape[-1] // 2
+        x = x[..., :half] + x[..., half:]
+    return jnp.sum(x, -1, keepdims=True)
+
+
 def norm(params: Dict, x: jnp.ndarray, kind: str, eps: float) -> jnp.ndarray:
     xf = x.astype(jnp.float32)
     if kind == "rmsnorm":
-        xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
+        ms = _sum_last(xf * xf) / xf.shape[-1]
+        xf = xf * jax.lax.rsqrt(ms + eps)
         return (xf * (1.0 + params["scale"].astype(jnp.float32))).astype(x.dtype)
     mean = jnp.mean(xf, -1, keepdims=True)
     var = jnp.var(xf, -1, keepdims=True)

@@ -439,7 +439,13 @@ class PagedCacheStore:
     Shapes (S = sequence slots, P = n_pages + 1 trash, ps = page_size,
     NB = max logical blocks per sequence):
 
-      k/v_data, k/v_meta  int8  [P, ps, KV, hd]   packed §5.1 page pools
+      k/v_data, k/v_meta  int8  [P, ps, KV*hd]  packed §5.1 page pools,
+                                                  lane-dense: the bytes of
+                                                  [P, ps, KV, hd] with the
+                                                  head axes flattened (the
+                                                  TPU layout the kernels
+                                                  read; see
+                                                  kernels.sparq_decode_attn)
       k/v_scale           f32   [S]               per-sequence site scales
                                                   (0 = uncalibrated; set by
                                                   adopt_prefill, frozen for
@@ -478,7 +484,7 @@ class PagedCacheStore:
                 "point of the pool is that the hot loop reads packed bytes)")
         assert head_dim % 2 == 0, \
             f"sparq pairs adjacent lanes; head_dim must be even: {head_dim}"
-        shp = (n_pages + 1, page_size, kv_heads, head_dim)  # +1: trash page
+        shp = (n_pages + 1, page_size, kv_heads * head_dim)  # +1: trash
         return PagedCacheStore(
             k_data=jnp.zeros(shp, jnp.int8),
             k_meta=jnp.zeros(shp, jnp.int8),
@@ -497,11 +503,11 @@ class PagedCacheStore:
 
     @property
     def page_size(self) -> int:
-        return self.k_data.shape[-3]
+        return self.k_data.shape[-2]
 
     @property
     def n_pages(self) -> int:        # usable pages (excludes the trash page)
-        return self.k_data.shape[-4] - 1
+        return self.k_data.shape[-3] - 1
 
     @property
     def n_blocks(self) -> int:
@@ -518,7 +524,9 @@ class PagedCacheStore:
         return jnp.where(stored > 0, stored, dyn)
 
     def _encode(self, x: jnp.ndarray, scale: jnp.ndarray):
-        """float [S, KV, hd] -> (§5.1 window codes, meta bytes), int8.
+        """float [S, KV, hd] -> (§5.1 window codes, meta bytes), int8
+        [S, KV*hd] (lane-dense rows of the pool; hd is even, so flattening
+        never splits a vSPARQ pair).
 
         Same codec semantics as CachedTensor._encode but with a per-slot
         scale vector; the reference quantizer is elementwise over leading
@@ -529,8 +537,9 @@ class PagedCacheStore:
         from repro.kernels import ref as _ref
         from repro.kernels.ops import sparq_pack
         cfg = self.codec
+        x = x.reshape(x.shape[0], -1)
         codes, meta = _ref.ref_sparq_quant(
-            x.astype(jnp.float32), scale[:, None, None],
+            x.astype(jnp.float32), scale[:, None],
             bits=cfg.bits, opts_shifts=cfg.shifts, rounding=cfg.rounding,
             vsparq=cfg.vsparq, signed=cfg.signed, max_val=cfg.max_val,
             enabled=cfg.enabled)
@@ -724,10 +733,10 @@ def adopt_prefill(store: PagedCacheStore, cs: CacheStore,
     """
     nbp = pages.shape[0]
     L = store.k_data.shape[0]
-    ps = store.k_data.shape[-3]
+    ps = store.page_size
 
     def put(pool, plane):        # plane [L, 1, nbp*ps, KV, hd]
-        blocks = plane.reshape(L, nbp, ps, *plane.shape[3:])
+        blocks = plane.reshape(L, nbp, ps, pool.shape[-1])
         return pool.at[:, pages].set(blocks)
 
     bt_row = jnp.full((store.block_table.shape[-1],), -1,
@@ -806,7 +815,7 @@ def gather_slot_pages(store: PagedCacheStore, slot: jnp.ndarray,
 
     `store` is layer-stacked; `pages` ([nbp] int32) are the physical pages
     the slot owns, in block order. Returns a dict of device arrays — each
-    pool plane gathered at `pages` ([L, nbp, ps, KV, hd] int8) plus the
+    pool plane gathered at `pages` ([L, nbp, ps, KV*hd] int8) plus the
     per-layer scales ([L] f32). A pure gather of the raw §5.1 bytes: no
     dequantization, no requantization — what leaves the pool is exactly
     what `restore_slot_pages` puts back, so a swap round trip is

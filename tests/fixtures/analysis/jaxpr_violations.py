@@ -49,14 +49,27 @@ def _decode_kernel(p_ref, o_ref):
 
 
 def page_tile_mismatch(planes):
-    """JX104: int8 plane tiled at 8 rows/page in a program whose spec
-    declares page_size=16 — the paged read no longer aligns to pages."""
+    """JX104: lane-dense int8 pool [P, ps, KV*hd] tiled at 8 rows/page in
+    a program whose spec declares page_size=16 — the paged read no longer
+    aligns to pages."""
     return pl.pallas_call(
         _decode_kernel,
         out_shape=jax.ShapeDtypeStruct(planes.shape, jnp.float32),
         grid=(4, 2),
-        in_specs=[pl.BlockSpec((1, 8, 2, 8), lambda i, j: (i, j, 0, 0))],
-        out_specs=pl.BlockSpec((1, 8, 2, 8), lambda i, j: (i, j, 0, 0)),
+        in_specs=[pl.BlockSpec((1, 8, 16), lambda i, j: (i, j, 0))],
+        out_specs=pl.BlockSpec((1, 8, 16), lambda i, j: (i, j, 0)),
+        interpret=True)(planes)
+
+
+def page_head_split(planes):
+    """JX104 again: whole pages, but each block takes half the lane axis
+    (one head of two) — a head-split tile the TPU tiling rule refuses."""
+    return pl.pallas_call(
+        _decode_kernel,
+        out_shape=jax.ShapeDtypeStruct(planes.shape, jnp.float32),
+        grid=(4, 2),
+        in_specs=[pl.BlockSpec((1, 16, 8), lambda i, j: (i, 0, j))],
+        out_specs=pl.BlockSpec((1, 16, 8), lambda i, j: (i, 0, j)),
         interpret=True)(planes)
 
 
@@ -82,7 +95,6 @@ def shard_map_hostcall(x):
     walk through the shard_map eqn's inner jaxpr (the tensor-parallel
     decode/prefill programs all trace through one), not just pjit cores.
     A 1-device mesh keeps the fixture traceable on any host."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = jax.make_mesh((1,), ("model",))
 
@@ -90,5 +102,5 @@ def shard_map_hostcall(x):
         jax.debug.callback(_sink, v)
         return v + 1
 
-    return shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
-                     check_rep=False)(x)
+    return jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)(x)

@@ -56,11 +56,11 @@ JX_CASES = [
      JX_PACKED_CAST),
     ("tile_misdivide", [((48, 16), jnp.float32)], {}, DEFAULT_VMEM_BUDGET,
      JX_TILE_DIVIDE),
-    ("page_tile_mismatch", [((4, 16, 2, 8), jnp.int8)],
+    ("page_tile_mismatch", [((4, 16, 16), jnp.int8)],
      {"page_size": 16}, DEFAULT_VMEM_BUDGET, JX_PAGE_TILE),
-    # whole-array f32 blocks: 2 * 256*256*4 = 512 KiB > the 256 KiB
-    # test budget (and well under the default budget, so only JX105
-    # distinguishes this case)
+    # whole-array f32 blocks, in and out, each double-buffered:
+    # 4 * 256*256*4 = 1 MiB > the 256 KiB test budget (and well under
+    # the default budget, so only JX105 distinguishes this case)
     ("vmem_hog", [((256, 256), jnp.float32)], {}, 256 * 1024, JX_VMEM),
 ]
 
@@ -76,6 +76,27 @@ def test_jaxpr_check_fires_exactly_once(jaxpr_fixture, fn, argspec, kw,
         [f.format() for f in findings]
     assert n_sig == 1
     assert findings[0].program == fn
+
+
+def test_page_tile_check_fires_on_head_split(jaxpr_fixture):
+    """JX104 also catches whole-page tiles that split the lane axis."""
+    spec = ProgramSpec("page_head_split", jaxpr_fixture.page_head_split,
+                       [(_sds((4, 16, 16), jnp.int8),)], page_size=16)
+    findings, _ = audit_program(spec)
+    assert [f.check for f in findings] == [JX_PAGE_TILE], \
+        [f.format() for f in findings]
+
+
+@pytest.mark.parametrize("dims,dtype,want", [
+    ((16, 64), jnp.int8, 32 * 128),          # int8 sublane tile is 32
+    ((1, 16, 256), jnp.int8, 32 * 256),
+    ((8, 128), jnp.float32, 8 * 128 * 4),    # already tile-aligned
+    ((64, 1), jnp.float32, 64 * 128 * 4),    # a column takes 128 lanes
+    ((4, 8, 64), jnp.bfloat16, 4 * 16 * 128 * 2),
+])
+def test_vmem_tile_bytes_pads_to_tpu_tiles(dims, dtype, want):
+    from repro.analysis.jaxpr_audit import vmem_tile_bytes
+    assert vmem_tile_bytes(dims, dtype) == want
 
 
 def test_compile_cache_check_fires_exactly_once(jaxpr_fixture):

@@ -105,6 +105,24 @@ def test_wrapper_pads_and_unpads():
                                rtol=1e-5, atol=1e-5)
 
 
+def test_dense_pallas_and_reference_programs_agree_bitwise():
+    """Inside a model's program the scales are made next to the matmul
+    (`s / qmax`, `max|w| / 127`). The oracle must not let XLA fold those
+    constants into its scale chain: the two programs then give the same
+    bits, as the kernel reads its scales from memory."""
+    from repro.models.common import QuantCtx, dense
+    cfg = SparqConfig.opt5(signed=True)
+    x = jax.random.normal(KEY, (256, 256))
+    w = jax.random.normal(jax.random.PRNGKey(2), (256, 128)) / 16
+
+    def program(impl):
+        return jax.jit(lambda w, x, s: dense(w, x, "w", QuantCtx(
+            mode="quantized", cfg=cfg, impl=impl, scales={"w": s})))
+    s = jnp.max(jnp.abs(x))
+    np.testing.assert_array_equal(np.asarray(program("pallas")(w, x, s)),
+                                  np.asarray(program("reference")(w, x, s)))
+
+
 @pytest.mark.parametrize("cfg", [SparqConfig.opt5(signed=True),
                                  SparqConfig.opt3(signed=True),
                                  SparqConfig.opt6(signed=True)],

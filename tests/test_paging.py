@@ -51,15 +51,16 @@ def _scatter_pool(rng, kd, km, vd, vm, ps):
     NB = Tk // ps
     P = B * NB + 2
     pages = rng.permutation(P)[: B * NB].reshape(B, NB)
-    pool = lambda: np.zeros((P, ps, KV, hd), np.int8)
+    pool = lambda: np.zeros((P, ps, KV * hd), np.int8)   # lane-dense
     pk, pkm, pv, pvm = pool(), pool(), pool(), pool()
+    row = lambda x, b, sl: np.asarray(x[b, sl]).reshape(ps, KV * hd)
     for b in range(B):
         for t in range(NB):
             sl = slice(t * ps, (t + 1) * ps)
-            pk[pages[b, t]] = np.asarray(kd[b, sl])
-            pkm[pages[b, t]] = np.asarray(km[b, sl])
-            pv[pages[b, t]] = np.asarray(vd[b, sl])
-            pvm[pages[b, t]] = np.asarray(vm[b, sl])
+            pk[pages[b, t]] = row(kd, b, sl)
+            pkm[pages[b, t]] = row(km, b, sl)
+            pv[pages[b, t]] = row(vd, b, sl)
+            pvm[pages[b, t]] = row(vm, b, sl)
     return (jnp.asarray(pk), jnp.asarray(pkm), jnp.asarray(pv),
             jnp.asarray(pvm), jnp.asarray(pages, jnp.int32))
 

@@ -118,9 +118,10 @@ class TestFitSpec:
 
 class TestPoolSpecs:
     def test_plane_pspec_targets_kv_head_axis(self):
-        # packed plane [P, ps, KV, 2*hd] and stacked [L, P, ps, KV, hd]
-        assert pool_plane_pspec(4) == P(None, None, "model", None)
-        assert pool_plane_pspec(5) == P(None, None, None, "model", None)
+        # lane-dense plane [P, ps, KV*hd] and stacked [L, P, ps, KV*hd]:
+        # the KV-major lane axis shards, so each device holds whole heads
+        assert pool_plane_pspec(3) == P(None, None, "model")
+        assert pool_plane_pspec(4) == P(None, None, None, "model")
 
     def test_store_tree_pools_shard_bookkeeping_replicated(self):
         from repro.launch.serve import ContinuousBatchingEngine  # noqa: F401
@@ -138,9 +139,9 @@ class TestPoolSpecs:
         for name in ("k_data", "k_meta", "v_data", "v_meta"):
             plane = getattr(store, name)
             spec = getattr(specs, name)
-            assert spec[plane.ndim - 2] == "model"
+            assert spec[plane.ndim - 1] == "model"
             assert all(s is None for i, s in enumerate(spec)
-                       if i != plane.ndim - 2)
+                       if i != plane.ndim - 1)
         for name in ("k_scale", "v_scale", "block_table", "seq_pos"):
             assert getattr(specs, name) == P()
 

@@ -168,7 +168,7 @@ def test_pool_planes_physically_sharded(tp_lm):
     for name in ("k_data", "k_meta", "v_data", "v_meta"):
         plane = getattr(first, name)
         shard = plane.sharding.shard_shape(plane.shape)
-        kv_ax = plane.ndim - 2
+        kv_ax = plane.ndim - 1          # lane-dense [..., KV*hd], KV-major
         assert shard[kv_ax] == plane.shape[kv_ax] // 4, name
         assert all(shard[i] == plane.shape[i]
                    for i in range(plane.ndim) if i != kv_ax), name
@@ -189,6 +189,34 @@ def test_kv_head_divisibility_guard(tp_lm):
         _engine(model, "5opt", "requeue", tp=8)
 
 
+@needs8
+@pytest.mark.parametrize("tp", [2, 8])
+def test_pallas_matmul_replicated_under_tp_mesh(tp):
+    """The Pallas quantized matmul cannot be partitioned by the compiler;
+    under a TP mesh it runs in a shard_map, each device on its own output
+    columns (48 columns over 2 or 8 devices), and returns the TP=1
+    product bit for bit, inside jit as the engine calls it. The product
+    leaves replicated: a column-sharded one lets GSPMD split the next
+    RMSNorm's sum into partial sums, and the tokens drift from TP=1."""
+    from repro.core.quantizer import QScale
+    from repro.kernels import ops
+    from repro.launch.mesh import make_tp_mesh
+    cfg = CODECS["5opt"]()
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.standard_normal((6, 64)), jnp.float32)
+    w = jnp.asarray(rng.integers(-127, 128, (64, 48)), jnp.int8)
+    cs = jnp.asarray(rng.random(48) * 0.01, jnp.float32)
+    qs = QScale(scale=jnp.float32(0.02), bits=cfg.act_bits, signed=True)
+
+    def mm(mesh):
+        return jax.jit(lambda x, w, c: ops.quantized_matmul(
+            x, w, qs, c, cfg, impl="pallas", block=(8, 16, 32),
+            mesh=mesh))(x, w, cs)
+    got = mm(make_tp_mesh(tp))
+    assert got.sharding.is_fully_replicated
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(mm(None)))
+
+
 # ----------------------------------------------------------------------
 # self-provisioning wrapper: one bounded TP slice under plain tier-1
 # ----------------------------------------------------------------------
@@ -201,7 +229,8 @@ def test_kv_head_divisibility_guard(tp_lm):
 def test_tp_slice_in_forced_device_subprocess():
     """Single-device runs still get TP coverage: re-spawn pytest on this
     file with the forced 8-device CPU flag and a bounded `-k` slice (one
-    token-equality cell + the accounting grid + the guard). The full
+    token-equality cell + the accounting grid + the guard + the
+    column-split Pallas matmul). The full
     matrix runs in CI's multidevice job."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
@@ -216,9 +245,9 @@ def test_tp_slice_in_forced_device_subprocess():
         [sys.executable, "-m", "pytest", os.path.abspath(__file__), "-q",
          "-p", "no:cacheprovider",
          "-k", ("tp2-a8w8-requeue or per_device_pool_accounting "
-                "or divisibility_guard")],
+                "or divisibility_guard or pallas_matmul_replicated")],
         cwd=root, env=env, capture_output=True, text=True, timeout=1200)
     assert proc.returncode == 0, \
         f"TP subprocess failed:\n{proc.stdout}\n{proc.stderr}"
-    # the -k slice selects 6 tests; none may be skipped for device count
-    assert "6 passed" in proc.stdout, proc.stdout
+    # the -k slice selects 8 tests; none may be skipped for device count
+    assert "8 passed" in proc.stdout, proc.stdout
