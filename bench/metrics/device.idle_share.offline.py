@@ -1,0 +1,10 @@
+"""Share of the traced window in which no operation ran on the device,
+in percent: 1 - busy / window, busy the union of the device's op
+intervals (averaged over chips)."""
+
+
+def read(obs):
+    t = obs.trace
+    if t is None or t.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)
