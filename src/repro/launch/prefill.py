@@ -95,6 +95,13 @@ class ChunkPlan(NamedTuple):
     completed: List[Tuple[int, int, Optional[int]]]  # (slot, rid, expect)
     advanced: Dict[int, int]            # slot -> prompt tokens written
 
+    def seqs(self) -> List[List[int]]:
+        """Per slot in the chunk, by slot: [history boundary, tokens]."""
+        live = self.seq_id >= 0
+        return [[int(self.hist[m].min()), int(m.sum())]
+                for m in (self.seq_id == s
+                          for s in np.unique(self.seq_id[live]))]
+
 
 class PrefillScheduler:
     """Packs ragged pending prompts into fixed-shape chunks and runs them

@@ -764,6 +764,9 @@ class ContinuousBatchingEngine:
             # of landing in state nobody is serving
             self._run_live.clear()
             self._live = None
+            # a dead loop never reached run_end: drop the tracer's
+            # process-global hooks (gc callbacks, the compile listener)
+            self.telemetry.spans.runtime_off()
 
     def _run_impl(self, params, requests, progress, trace_hook,
                   emit, clock_mode, drain):
@@ -1486,23 +1489,31 @@ class ContinuousBatchingEngine:
                                    max(self.prefill_priority, 1.0))
                 chunk_gated = chunk_credit < 1.0
                 while chunk_credit >= 1.0 and sched.pending:
+                    # hand-off stamps (timed runs): plan | pages.table |
+                    # dispatch | wait | emit, one contiguous stretch
+                    t_plan = time.perf_counter() if timed else 0.0
                     plan = sched.plan(prefill_budget, grant, host_bt)
                     if plan is None:
+                        if timed:
+                            sp.handoff("chunk.plan", t_plan,
+                                       time.perf_counter())
                         break
                     chunk_credit -= 1.0
-                    bt_dev = self._replicated(jnp.asarray(host_bt, jnp.int32))
-                    caches = [dataclasses.replace(
-                        c, block_table=jnp.broadcast_to(
-                            bt_dev, c.block_table.shape))
-                        for c in caches]
                     spa = np.full((S,), -1, np.int64)
                     for s2 in range(S):
                         if slots[s2] is not None and not sched.has(s2):
                             spa[s2] = host_pos[s2]
                     for s2, _, _ in plan.completed:
                         spa[s2] = host_pos[s2] + plan.advanced[s2]
+                    t_bt = time.perf_counter() if timed else 0.0
+                    bt_dev = self._replicated(jnp.asarray(host_bt, jnp.int32))
+                    caches = [dataclasses.replace(
+                        c, block_table=jnp.broadcast_to(
+                            bt_dev, c.block_table.shape))
+                        for c in caches]
                     t0 = time.perf_counter()
                     am, caches = sched.run(params, caches, plan, spa)
+                    t_run = time.perf_counter() if timed else 0.0
                     jax.block_until_ready(am)
                     t1 = time.perf_counter()
                     c_t_prefill.inc(t1 - t0)
@@ -1542,6 +1553,15 @@ class ContinuousBatchingEngine:
                                   f"complete at pos {host_pos[s2]}")
                     g_pages.set(allocator.used_count)
                     g_peak.set_max(allocator.used_count)
+                    if sp.on:
+                        sp.handoff("chunk.plan", t_plan, t_bt)
+                        sp.handoff("pages.table", t_bt, t0)
+                        sp.handoff("chunk.dispatch", t0, t_run,
+                                   tokens=int((plan.seq_id >= 0).sum()),
+                                   seqs=plan.seqs(),
+                                   completed=len(plan.completed))
+                        sp.handoff("chunk.wait", t_run, t1)
+                        sp.handoff("chunk.emit", t1, time.perf_counter())
 
             if not any(slots):
                 if resume_q or arrived():
@@ -1573,6 +1593,7 @@ class ContinuousBatchingEngine:
             # a PoolExhausted mid-step (no victim left) cannot strand
             # pages — asserted by check_page_accounting every iteration.
             dirty = False
+            grown = 0
             for s in range(S):
                 if slots[s] is None or slots[s].generated >= slots[s].target:
                     continue
@@ -1610,16 +1631,20 @@ class ContinuousBatchingEngine:
                 (pg,) = allocator.alloc(1)
                 slots[s].pages.append(pg)
                 host_bt[s, blk] = pg
-                dirty = True
+                grown += 1
             g_pages.set(allocator.used_count)
             g_peak.set_max(allocator.used_count)
+            dirty = dirty or grown > 0
+            t_grow = time.perf_counter() if timed else 0.0
             if dirty:
                 bt_dev = self._replicated(jnp.asarray(host_bt, jnp.int32))
                 caches = [dataclasses.replace(
                     c, block_table=jnp.broadcast_to(
                         bt_dev, c.block_table.shape))
                     for c in caches]
+            t_table = time.perf_counter() if timed else 0.0
             check_page_accounting()
+            t_check = time.perf_counter() if timed else 0.0
 
             # ---- one traced decode step over every slot. Slots that just
             # hit their target still ride along (their masked write lands
@@ -1665,6 +1690,7 @@ class ContinuousBatchingEngine:
                     preempt(victim)
                 continue                        # every slot done: evict
             if trace_hook is not None or sp.on:
+                t_snap = time.perf_counter() if timed else 0.0
                 snap = self._snapshot(
                     n_steps, allocator, slots, host_bt, host_pos, caches,
                     [e for e in queue if e[1] not in cancelled],
@@ -1681,8 +1707,13 @@ class ContinuousBatchingEngine:
                              "active": len(active),
                              "queued": len(snap["queued"]),
                              "swapped": len(snap["swapped_rids"])})
+            if sp.on:
+                step_args = {"rows": len(active),
+                             "ctx": [int(host_pos[s]) + 1 for s, _ in active]}
+            t_disp = time.perf_counter() if timed else 0.0
             pos_dev = caches[0].seq_pos[0]      # [S]; host_pos for active
             tok, caches = self._step(params, tok, caches, pos_dev)
+            t_sent = time.perf_counter() if timed else 0.0
             n_steps += 1
             c_steps.inc()
             c_tokens.inc(len(active))
@@ -1727,6 +1758,16 @@ class ContinuousBatchingEngine:
                 h_admit.observe(t_prefill0 - t_admit0)
                 h_prefill.observe(t_decode0 - t_prefill0)
                 h_decode.observe(t_it1 - t_decode0)
+                if sp.on:
+                    sp.handoff("pages.grow", t_decode0, t_grow, pages=grown)
+                    if dirty:
+                        sp.handoff("pages.table", t_grow, t_table)
+                    sp.handoff("pages.check", t_table, t_check)
+                    sp.handoff("trace.snapshot", t_snap, t_disp)
+                    sp.handoff("step.dispatch", t_disp, t_sent, **step_args)
+                    if emit is not None:
+                        sp.handoff("step.fetch", t_sent, t_step)
+                    sp.handoff("step.emit", t_step, t_it1)
                 sp.step(it_t0, t_it1,
                         phases=(("retire", it_t0, t_admit0),
                                 ("admit", t_admit0, t_prefill0),

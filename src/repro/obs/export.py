@@ -7,8 +7,6 @@
   smoke step to assert the dump round-trips.
 - ``write_trace(tracer, path)`` — Chrome trace-event JSON envelope
   (``{"traceEvents": [...]}``) loadable in Perfetto / chrome://tracing.
-- ``write_events_jsonl`` / ``write_metrics_jsonl`` — one-JSON-object-
-  per-line logs for offline processing.
 - ``MetricsServer`` — a dependency-free asyncio HTTP listener serving
   ``GET /metrics`` from a live registry (attached to the async
   front-end's event loop; the engine thread never blocks on it).
@@ -110,25 +108,6 @@ def trace_json(tracer):
 def write_trace(tracer, path):
     with open(path, "w") as f:
         json.dump(trace_json(tracer), f)
-
-
-def write_events_jsonl(tracer, path):
-    with open(path, "w") as f:
-        for ev in tracer.events():
-            f.write(json.dumps(ev) + "\n")
-
-
-def write_metrics_jsonl(registry, path):
-    with open(path, "w") as f:
-        for m in registry.collect():
-            for labels, s in m.samples():
-                rec = {"name": m.name, "kind": m.kind, "labels": labels}
-                if m.kind == "histogram":
-                    rec.update(count=s.count, sum=s.sum,
-                               p50=s.percentile(50), p99=s.percentile(99))
-                else:
-                    rec["value"] = s.value()
-                f.write(json.dumps(rec) + "\n")
 
 
 class MetricsServer:

@@ -10,22 +10,26 @@ Two layers:
 - ``EngineSpans`` — the serving engine's view: a per-request span state
   machine (submitted -> queued -> prefill -> decode -> preempted/
   resumed -> finished/cancelled) plus per-iteration scheduler step
-  spans with phase children (retire/admit/prefill/decode) and counter
-  tracks fed from the engine's existing ``trace_hook`` snapshot point.
+  spans with phase children (retire/admit/prefill/decode), hand-off
+  spans inside the phases (``HANDOFFS``: each place the host hands work
+  to the device, waits for it, or works between the two), a runtime
+  track of garbage collections and XLA compiles, and counter tracks fed
+  from the engine's existing ``trace_hook`` snapshot point.
   Every method is a no-op when no tracer is attached, so the engine
   calls them unconditionally and pays one attribute test per site when
   tracing is off.
 
 Track layout: pid 0, tid 0 is the scheduler; request ``rid`` gets
-tid ``rid + 1``.  All timestamps are host ``time.perf_counter()``
-floats — reading a token *value* for a trace event would force a
-device sync, so span boundaries only ever use host-side stamps the
-engine already takes (HL202: the one batched ``jax.device_get`` per
-step remains the only transfer).
+tid ``rid + 1``; the runtime track is ``RUNTIME_TID``.  All timestamps
+are host ``time.perf_counter()`` floats — reading a token *value* for
+a trace event would force a device sync, so span boundaries only ever
+use host-side stamps the engine already takes (HL202: the one batched
+``jax.device_get`` per step remains the only transfer).
 """
 
 from __future__ import annotations
 
+import gc
 import time
 
 __analysis__ = {
@@ -37,6 +41,12 @@ __analysis__ = {
 }
 
 SCHED_TID = 0
+#: garbage collections and XLA compiles, whichever thread ran them: a
+#: track of its own, above every request's tid, so that it never breaks
+#: the scheduler track's nesting
+RUNTIME_TID = 2 ** 31 - 1
+#: the `jax.monitoring` duration event of one XLA backend compile
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 def _tid(rid):
@@ -124,12 +134,20 @@ class EngineSpans:
     """
 
     PHASES = ("queued", "prefill", "decode", "preempted")
+    #: scheduler sub-spans of an iteration's prefill and decode phases
+    HANDOFFS = ("chunk.plan", "pages.table", "chunk.dispatch", "chunk.wait",
+                "chunk.emit", "pages.grow", "pages.check", "trace.snapshot",
+                "step.dispatch", "step.fetch", "step.emit")
 
     def __init__(self, tracer=None):
         self._tr = tracer
         self._open = {}          # rid -> current phase name
         self._chunk_idx = {}     # rid -> prefill chunk ordinal
         self._step_idx = 0
+        self._handoffs = []      # this iteration's (name, t0, t1, args)
+        self._hooked = False
+        self._t_run0 = 0.0
+        self._gc_t0 = None
 
     @property
     def on(self):
@@ -223,8 +241,18 @@ class EngineSpans:
         self._chunk_idx.pop(rid, None)
 
     # -- scheduler ---------------------------------------------------------
+    def handoff(self, name, t0, t1, **args):
+        """One of ``HANDOFFS`` in the current iteration. Held until the
+        iteration's ``step`` so that it nests in the iteration's phase
+        span; an iteration that runs no decode step emits no step span,
+        and its hand-offs go with it."""
+        if self._tr is None:
+            return
+        self._handoffs.append((name, t0, t1, args))
+
     def step(self, t0, t1, phases=(), **args):
-        """One scheduler iteration: parent X span + phase X children.
+        """One scheduler iteration: parent X span + phase X children,
+        then the iteration's hand-off spans inside the phases.
 
         ``phases`` is ``[(name, p0, p1), ...]`` with host stamps taken
         around the retire/admit/prefill/decode regions of the loop.
@@ -238,6 +266,10 @@ class EngineSpans:
         tr.complete(SCHED_TID, f"step[{i}]", t0, t1, **args)
         for name, p0, p1 in phases:
             tr.complete(SCHED_TID, name, p0, p1)
+        for name, h0, h1, h_args in self._handoffs:
+            if h0 >= t0:    # not from an iteration that ran no step
+                tr.complete(SCHED_TID, name, h0, h1, **h_args)
+        self._handoffs = []
 
     def snapshot(self, snap, t=None):
         """Counter tracks from the engine's trace_hook snapshot dict."""
@@ -253,6 +285,46 @@ class EngineSpans:
                     "queued": snap.get("queued", 0),
                     "swapped": snap.get("swapped", 0)}, t)
 
+    # -- runtime track -----------------------------------------------------
+    def _on_gc(self, phase, info):
+        """``gc.callbacks`` hook: one span per collection."""
+        if phase == "start":
+            self._gc_t0 = time.perf_counter()
+        elif self._gc_t0 is not None:
+            self._tr.complete(RUNTIME_TID, "gc", self._gc_t0,
+                              time.perf_counter(),
+                              generation=info["generation"],
+                              collected=info["collected"])
+            self._gc_t0 = None
+
+    def _on_duration(self, event, duration, **_):
+        """``jax.monitoring`` listener: one span per backend compile,
+        placed as (now - duration, now) and clipped to the run."""
+        if event == COMPILE_EVENT:
+            t1 = time.perf_counter()
+            self._tr.complete(RUNTIME_TID, "compile",
+                              max(t1 - duration, self._t_run0), t1)
+
+    def _hook_runtime(self):
+        import jax.monitoring
+        self.runtime_off()
+        self._tr.thread_name(RUNTIME_TID, "runtime")
+        gc.callbacks.append(self._on_gc)
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration)
+        self._hooked = True
+
+    def runtime_off(self):
+        """Remove the runtime track's hooks (process-global); safe to
+        call at any time, and called at ``run_end``."""
+        if not self._hooked:
+            return
+        import jax.monitoring
+        gc.callbacks.remove(self._on_gc)
+        jax.monitoring.unregister_event_duration_listener(self._on_duration)
+        self._hooked = False
+        self._gc_t0 = None
+
     # -- run boundary ------------------------------------------------------
     def run_begin(self, t=None):
         if self._tr is None:
@@ -261,11 +333,15 @@ class EngineSpans:
         self._open = {}
         self._chunk_idx = {}
         self._step_idx = 0
-        self._tr.instant(SCHED_TID, "run_begin", t)
+        self._handoffs = []
+        self._t_run0 = time.perf_counter() if t is None else t
+        self._tr.instant(SCHED_TID, "run_begin", self._t_run0)
+        self._hook_runtime()
 
     def run_end(self, t=None):
         if self._tr is None:
             return
+        self.runtime_off()
         for rid in list(self._open):
             self._leave(rid, t)
         self._tr.instant(SCHED_TID, "run_end", t)

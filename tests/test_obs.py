@@ -16,6 +16,10 @@ What is proven here:
   * engine integration — a traced run's request-span tid set matches
     the emitted results exactly, every request shows first_token and
     finished marks, scheduler step spans carry the four phase children,
+    each hand-off span nests in its phase, an iteration's hand-offs do
+    not overlap and every dispatch is followed by its sync, the runtime
+    track records collections and compiles and its hooks are gone after
+    the run, the untraced loop reads the clock no more than it did,
     and the stats dict the engine returns is value-identical to direct
     registry reads (back-compat: the old `counters`/`pstats` keys now
     have exactly one source of truth);
@@ -26,7 +30,10 @@ What is proven here:
 """
 import asyncio
 import dataclasses
+import gc
 import json
+import time
+import types
 
 import jax
 import jax.numpy as jnp
@@ -34,12 +41,13 @@ import numpy as np
 import pytest
 
 from repro.core.sparq import SparqConfig
-from repro.launch import frontend
+from repro.launch import frontend, serve
 from repro.launch.serve import (ContinuousBatchingEngine, Request,
                                 SchedulerPolicy)
 from repro.models.cache import CacheConfig
 from repro.obs import (EngineSpans, MetricsRegistry, Telemetry, Tracer,
                        export, summary_ms)
+from repro.obs.tracing import RUNTIME_TID, SCHED_TID
 
 KEY = jax.random.PRNGKey(0)
 PS = 4
@@ -438,7 +446,8 @@ def test_engine_trace_schema(runs):
         assert {"retire", "admit", "prefill", "decode"} <= phase_names
         # request span set == emitted requests, each with a full arc
         rid_tids = {e["tid"] for e in evs
-                    if e["ph"] in ("B", "E", "X", "i") and e["tid"] != 0}
+                    if e["ph"] in ("B", "E", "X", "i")
+                    and e["tid"] not in (SCHED_TID, RUNTIME_TID)}
         assert rid_tids == {rid + 1 for rid in r["res"]}
         for rid in r["res"]:
             names = [e.get("name") for e in evs if e["tid"] == rid + 1]
@@ -458,3 +467,169 @@ def test_engine_prometheus_dump(runs):
         if mode == "swap":
             assert parsed[("swap_bytes_total", 'dir="out"')] == \
                 r["stats"]["swap_bytes_out"]
+
+
+# ----------------------------------------------------------------------
+# hand-off spans and the runtime track: one streaming (emit) run with
+# chunked prefill and preemption, traced, and the same run untraced
+# ----------------------------------------------------------------------
+
+#: the phase span each hand-off nests in
+HANDOFF_PHASE = {"chunk.plan": ("prefill",), "chunk.dispatch": ("prefill",),
+                 "chunk.wait": ("prefill",), "chunk.emit": ("prefill",),
+                 "pages.table": ("prefill", "decode"),
+                 "pages.grow": ("decode",), "pages.check": ("decode",),
+                 "trace.snapshot": ("decode",), "step.dispatch": ("decode",),
+                 "step.fetch": ("decode",), "step.emit": ("decode",)}
+EPS_US = 1e-3                   # float rounding of the relative stamps
+
+
+def _emit_collecting(first):
+    """An emit callback that forces one full collection at the first
+    token, so the run's runtime track holds a gc span."""
+    def emit(rid, tok, final, t):
+        if not first:
+            first.append(rid)
+            gc.collect()
+    return emit
+
+
+@pytest.fixture(scope="module")
+def streamed(tiny_lm):
+    model, params = tiny_lm
+    reqs = _mk_reqs(model, shared=True)
+    tel = Telemetry.tracing()
+    eng = _engine(model, "requeue", tel)
+    res, stats = eng.run(params, reqs, emit=_emit_collecting([]))
+    return dict(tel=tel, res=res, stats=stats, evs=tel.tracer.events(),
+                model=model, params=params, reqs=reqs)
+
+
+def _iterations(evs):
+    """Per scheduler step: (step event, {phase: event}, [hand-offs])."""
+    sched = sorted((e for e in evs if e["ph"] == "X"
+                    and e["tid"] == SCHED_TID), key=lambda e: e["ts"])
+    steps = [e for e in sched if e["name"].startswith("step[")]
+    out = []
+    for st in steps:
+        a, b = st["ts"] - EPS_US, st["ts"] + st["dur"] + EPS_US
+        inner = [e for e in sched if e is not st
+                 and a <= e["ts"] and e["ts"] + e["dur"] <= b]
+        phases = {e["name"]: e for e in inner
+                  if e["name"] in ("retire", "admit", "prefill", "decode")}
+        hand = [e for e in inner if e["name"] in EngineSpans.HANDOFFS]
+        out.append((st, phases, hand))
+    return out
+
+
+def test_streamed_run_chunks_and_preempts(streamed):
+    st = streamed["stats"]
+    assert st["prefill_chunks"] >= 2 and st["preemptions"] >= 1
+    names = {e["name"] for e in streamed["evs"] if e["ph"] == "X"}
+    assert set(EngineSpans.HANDOFFS) <= names
+
+
+def test_handoff_spans_nest_in_their_phase(streamed):
+    evs = streamed["evs"]
+    n = sum(1 for e in evs if e["ph"] == "X"
+            and e["name"] in EngineSpans.HANDOFFS)
+    seen = 0
+    for _, phases, hand in _iterations(evs):
+        for h in hand:
+            ok = [p for p in HANDOFF_PHASE[h["name"]] if p in phases
+                  and phases[p]["ts"] - EPS_US <= h["ts"]
+                  and h["ts"] + h["dur"]
+                  <= phases[p]["ts"] + phases[p]["dur"] + EPS_US]
+            assert ok, (h, phases)
+            seen += 1
+    assert seen == n            # every hand-off lies in some iteration
+
+
+def test_handoff_spans_of_an_iteration_do_not_overlap(streamed):
+    for _, _, hand in _iterations(streamed["evs"]):
+        hand = sorted(hand, key=lambda e: e["ts"])
+        for a, b in zip(hand, hand[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"] + EPS_US, (a, b)
+
+
+def test_each_dispatch_is_followed_by_its_sync(streamed):
+    sync = {"chunk.dispatch": "chunk.wait", "step.dispatch": "step.fetch"}
+    n = 0
+    for _, _, hand in _iterations(streamed["evs"]):
+        hand = sorted(hand, key=lambda e: e["ts"])
+        for a, b in zip(hand, hand[1:] + [None]):
+            if a["name"] in sync:
+                assert b is not None and b["name"] == sync[a["name"]]
+                assert abs(a["ts"] + a["dur"] - b["ts"]) <= EPS_US
+                n += 1
+    assert n > 0
+
+
+def test_dispatch_args(streamed):
+    for _, _, hand in _iterations(streamed["evs"]):
+        for h in hand:
+            if h["name"] == "chunk.dispatch":
+                args = h["args"]
+                assert args["tokens"] == sum(t for _, t in args["seqs"])
+                assert 0 <= args["completed"] <= len(args["seqs"])
+            elif h["name"] == "step.dispatch":
+                assert h["args"]["rows"] == len(h["args"]["ctx"])
+            elif h["name"] == "pages.grow":
+                assert h["args"]["pages"] >= 0
+
+
+def test_streamed_trace_balances(streamed):
+    evs = json.loads(json.dumps(streamed["evs"]))
+    _check_balanced(evs)
+    rt = [e for e in evs if e["tid"] == RUNTIME_TID]
+    assert {"name": "thread_name", "ph": "M", "pid": 0, "tid": RUNTIME_TID,
+            "args": {"name": "runtime"}} in rt
+    gcs = [e for e in rt if e["name"] == "gc"]
+    assert any(e["args"]["generation"] == 2 for e in gcs)
+    assert all(set(e["args"]) == {"generation", "collected"} for e in gcs)
+    # the engine's first run compiles its programs
+    assert any(e["name"] == "compile" and e["ph"] == "X" for e in rt)
+
+
+def test_runtime_hooks_gone_after_run_end(streamed):
+    sp = streamed["tel"].spans
+    assert sp._on_gc not in gc.callbacks
+    n = len(streamed["tel"].tracer)
+    gc.collect()
+    jax.jit(lambda x: x * 3 + 1)(jnp.arange(5.0)).block_until_ready()
+    assert len(streamed["tel"].tracer) == n
+    with pytest.raises(AssertionError):     # not registered any more
+        jax.monitoring.unregister_event_duration_listener(sp._on_duration)
+
+
+def test_runtime_hooks_gone_after_a_failed_run(tiny_lm):
+    model, params = tiny_lm
+    tel = Telemetry.tracing()
+    eng = _engine(model, "requeue", tel)
+    n_cb = len(gc.callbacks)
+
+    def boom(*a):
+        raise RuntimeError("emit failed")
+    with pytest.raises(RuntimeError):
+        eng.run(params, _mk_reqs(model), emit=boom)
+    assert len(gc.callbacks) == n_cb
+    assert tel.spans._on_gc not in gc.callbacks
+
+
+def test_untraced_loop_reads_the_clock_as_before(streamed, monkeypatch):
+    """At the default level the loop takes no new clock reads: per run
+    2, per decode step 1, per chunk 3, per chunked resume 2 (the count
+    the loop had before the hand-off spans)."""
+    reads = []
+    shim = types.SimpleNamespace(
+        **{k: getattr(time, k) for k in dir(time) if not k.startswith("_")})
+    shim.perf_counter = lambda: reads.append(1) or time.perf_counter()
+    eng = _engine(streamed["model"], "requeue", Telemetry())
+    monkeypatch.setattr(serve, "time", shim)
+    res, st = eng.run(streamed["params"], streamed["reqs"],
+                      emit=lambda *a: None)
+    assert st["prefill_chunks"] >= 2 and st["resumes"] >= 1
+    assert len(reads) == 2 + st["decode_steps"] + 3 * st["prefill_chunks"] \
+        + 2 * st["resumes"]
+    for rid in res:
+        np.testing.assert_array_equal(res[rid], streamed["res"][rid])
