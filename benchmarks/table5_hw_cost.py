@@ -13,13 +13,7 @@ import jax.numpy as jnp
 
 from repro.core.sparq import SparqConfig
 from repro.kernels.ops import bytes_per_value
-from repro.kernels.sparq_matmul import sparq_matmul_pallas
-
-
-def vmem_working_set(bm, bn, bk) -> int:
-    """Bytes resident in VMEM per grid step: x tile (f32) + w tile (int8) +
-    acc scratch (int32) + recon tile (int32)."""
-    return bm * bk * 4 + bk * bn * 1 + bm * bn * 4 + bm * bk * 4
+from repro.kernels.sparq_matmul import sparq_matmul_pallas, vmem_bytes
 
 
 def kernel_cost(cfg: SparqConfig, m=256, k=1024, n=256,
@@ -40,7 +34,8 @@ def kernel_cost(cfg: SparqConfig, m=256, k=1024, n=256,
     return {
         "flops": float(cost.get("flops", -1)),
         "bytes": float(cost.get("bytes accessed", -1)),
-        "vmem_bytes": vmem_working_set(bm, bn, bk),
+        "vmem_bytes": vmem_bytes(bm, bn, bk, k, signed=cfg.signed,
+                                 max_val=cfg.max_val),
         "packed_bits_per_act": round(bytes_per_value(cfg) * 8, 2),
     }
 

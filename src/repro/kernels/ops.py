@@ -28,7 +28,7 @@ from repro.kernels.sparq_decode_attn import (sparq_decode_attn_pallas,
                                              sparq_paged_decode_attn_pallas)
 from repro.kernels.sparq_dequant import sparq_dequant_pallas
 from repro.kernels.sparq_prefill_attn import sparq_chunked_prefill_attn_pallas
-from repro.kernels.sparq_matmul import sparq_matmul_pallas
+from repro.kernels.sparq_matmul import choose_tiles, sparq_matmul_pallas
 from repro.kernels.sparq_quant import row_block, sparq_quant_pallas
 
 
@@ -138,7 +138,7 @@ def quantized_matmul(
     chan_scale: jnp.ndarray,   # (N,) f32
     cfg: SparqConfig,
     impl: str = "auto",
-    block: tuple[int, int, int] = (128, 128, 512),
+    block: Optional[tuple[int, int, int]] = None,
     mesh: Optional[Mesh] = None,
 ) -> jnp.ndarray:
     """SPARQ-quantized x @ dequant(w). Leading dims of x are flattened.
@@ -151,7 +151,10 @@ def quantized_matmul(
     The result leaves replicated: a column-sharded output would let
     GSPMD split later reductions over N (an RMSNorm's sum) into partial
     sums, which changes their order. When tp does not divide N every
-    device computes the whole product."""
+    device computes the whole product.
+
+    `block` is the kernel's (bm, bn, bk); None chooses them from
+    (M, K, N) and the codec (`sparq_matmul.choose_tiles`)."""
     if impl == "auto":
         impl = "pallas" if _on_tpu() else "reference"
     tp = tp_size(mesh)
@@ -180,8 +183,9 @@ def quantized_matmul(
     if impl == "reference":
         out = _ref.ref_sparq_matmul(x2, w_codes, act_qs.scale, chan_scale, **kw)
     elif impl == "pallas":
-        bm, bn, bk = block
         M = x2.shape[0]
+        bm, bn, bk = block or choose_tiles(M, K, N, signed=cfg.signed,
+                                           max_val=cfg.max_val)
         xp = _pad_to(_pad_to(x2, bm, 0), bk, 1)
         wp = _pad_to(_pad_to(w_codes, bk, 0), bn, 1)
         cp = _pad_to(chan_scale, bn, 0)

@@ -90,6 +90,54 @@ def test_matmul_kernel_dtypes(dtype):
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("cfg", [SparqConfig.opt5(signed=True),
+                                 SparqConfig.opt5(signed=False)],
+                         ids=["signed_int8", "unsigned_bf16"])
+@pytest.mark.parametrize("m", [24, 32, 200])
+def test_matmul_kernel_reuses_row_block_codes(m, cfg):
+    """Three column tiles over three K tiles: the first column tile
+    writes each row block's codes, the next two read them back. Ragged M
+    pads to 32-row blocks (200 rows: seven blocks, each its own codes)."""
+    k, n = 768, 384
+    x, w_codes, qs, cscale = _mk_inputs(m, k, n, cfg.signed)
+    got = ops.quantized_matmul(x, w_codes, qs, cscale, cfg, impl="pallas",
+                               block=(32, 128, 256))
+    want = kref.ref_sparq_matmul(
+        x, w_codes, qs.scale, cscale, bits=cfg.bits, opts_shifts=cfg.shifts,
+        rounding=cfg.rounding, vsparq=cfg.vsparq, signed=cfg.signed,
+        max_val=cfg.max_val, enabled=cfg.enabled)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# (K, N) of every quantized matmul of the benchmark's two configurations
+BENCH_MATMULS = {
+    "mistral_wq_wo": (12288, 12288), "mistral_wk_wv": (12288, 1024),
+    "mistral_w1_w3": (12288, 28672), "mistral_w2": (28672, 12288),
+    "starcoder2_wq_wo": (3072, 3072), "starcoder2_wk_wv": (3072, 256),
+    "starcoder2_w1": (3072, 12288), "starcoder2_w2": (12288, 3072),
+}
+
+
+@pytest.mark.parametrize("kn", BENCH_MATMULS.values(), ids=BENCH_MATMULS)
+def test_matmul_tiles_fit_the_benchmark_shapes(kn):
+    from repro.kernels import sparq_matmul as sm
+    K, N = kn
+    for m in (24, 32, 256):
+        for cfg in (SparqConfig.opt5(signed=True),
+                    SparqConfig.opt5(signed=False)):
+            bm, bn, bk = sm.choose_tiles(m, K, N, signed=cfg.signed,
+                                         max_val=cfg.max_val)
+            assert -(-m // 32) * 32 % bm == 0 and bm % 32 == 0
+            assert K % bk == 0 and N % bn == 0
+            assert bk % 2 == 0
+            assert cfg.signed or bk <= 512
+            need = sm.vmem_bytes(bm, bn, bk, K, signed=cfg.signed,
+                                 max_val=cfg.max_val)
+            assert need <= (sm.vmem_limit(need) or 16 << 20) <= 128 << 20
+            # decode rows run one row block, not a 128-row pad
+            assert bm == (32 if m <= 32 else 256)
+
+
 def test_wrapper_pads_and_unpads():
     cfg = SparqConfig.opt5(signed=True)
     x = jax.random.normal(KEY, (10, 6, 130))  # ragged everything

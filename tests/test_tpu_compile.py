@@ -136,10 +136,34 @@ def test_quantized_matmul_compiles(compile_v5e, on_tpu, M):
     assert "tpu_custom_call" in hlo
 
 
+# the benchmark's widths: Mistral-Large-2407 (d_model 12288, 8 KV heads of
+# 128, d_ff 28672) at a 32-row decode step and a 256-row chunk, and
+# StarCoder2-3B's MLP (d_model 3072, d_ff 12288) at 24 rows and 256
+BENCH_MATMULS = [(12288, 12288, 32), (12288, 1024, 32), (12288, 28672, 32),
+                 (28672, 12288, 32), (12288, 12288, 256), (12288, 1024, 256),
+                 (12288, 28672, 256), (28672, 12288, 256),
+                 (3072, 12288, 24), (12288, 3072, 24),
+                 (3072, 12288, 256), (12288, 3072, 256)]
+
+
+@pytest.mark.parametrize("K,N,M", BENCH_MATMULS,
+                         ids=[f"{k}x{n}_m{m}" for k, n, m in BENCH_MATMULS])
+def test_quantized_matmul_compiles_at_bench_widths(compile_v5e, on_tpu,
+                                                    K, N, M):
+    """The row block's codes scratch ([M, K] int8 at up to 28672 lanes)
+    and the weight tiles the kernel chooses fit v5e's VMEM."""
+    def fn(x, w, a, c):
+        return ops.quantized_matmul(x, w, _qscale(a), c, CODEC,
+                                    impl="pallas")
+    hlo = compile_v5e(fn, ((M, K), f32), ((K, N), i8), ((), f32),
+                      ((N,), f32))
+    assert "tpu_custom_call" in hlo
+
+
 def test_quantized_matmul_compiles_on_tp_mesh(compile_v5e, topo, on_tpu):
     """Under a 4-chip TP mesh the kernel must sit in a shard_map: the
     compiler refuses to partition a Mosaic kernel. Each chip computes a
-    quarter of the output columns: 512 of 2048 (128 padded rows)."""
+    quarter of the output columns: 512 of 2048 (one 32-row block)."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"),
@@ -152,7 +176,7 @@ def test_quantized_matmul_compiles_on_tp_mesh(compile_v5e, topo, on_tpu):
                       ((D,), f32), sharding=NamedSharding(mesh, P()))
     kernels = [line for line in hlo.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line]
-    assert kernels and all(f"f32[128,{D // 4}]" in line
+    assert kernels and all(f"f32[32,{D // 4}]" in line
                            for line in kernels), kernels
 
 
