@@ -65,11 +65,61 @@ def load_spec(name: str, root: pathlib.Path = ROOT) -> Spec:
     return Spec(name, conf, mix, settings, e2e, per_layer, int(w["chips"]))
 
 
+#: Published config.json keys beyond the dense ones, and the program's
+#: ModelConfig field each sets. A key is applied only where the file
+#: states it (a null states nothing); a stated key whose field the
+#: program lacks raises, and so does a field of this table that the
+#: registry entry sets and the file leaves unstated.
+MAPPED = {
+    "n_routed_experts": "n_experts",
+    "num_experts": "n_experts",
+    "num_experts_per_tok": "experts_per_token",
+    "moe_intermediate_size": "moe_d_ff",
+    "n_shared_experts": "n_shared_experts",
+    "first_k_dense_replace": "first_dense_layers",
+    "kv_lora_rank": "kv_lora_rank",
+    "q_lora_rank": "q_lora_rank",
+    "qk_nope_head_dim": "qk_nope_dim",
+    "qk_rope_head_dim": "qk_rope_dim",
+    "v_head_dim": "v_head_dim",
+    "norm_topk_prob": "norm_topk_prob",
+    "routed_scaling_factor": "routed_scaling_factor",
+    "rope_scaling": "rope_scaling",
+}
+
+
+def _mapped(conf: dict, base) -> dict:
+    """The MAPPED fields a configuration file sets."""
+    fields = {f.name: f.default for f in dataclasses.fields(base)}
+    out: Dict[str, Any] = {}
+    for key, field in MAPPED.items():
+        if conf.get(key) is None:
+            continue
+        if field not in fields:
+            raise ValueError(f"{conf['name']}: {key} = {conf[key]!r} has no "
+                             f"place: the program's ModelConfig has no "
+                             f"field {field!r}")
+        if field in out and out[field] != conf[key]:
+            raise ValueError(f"{conf['name']}: two keys set {field} to "
+                             f"{out[field]!r} and {conf[key]!r}")
+        out[field] = conf[key]
+    for field in sorted(set(MAPPED.values()) & set(fields)):
+        if field not in out and getattr(base, field) != fields[field]:
+            keys = [k for k, f in MAPPED.items() if f == field]
+            raise ValueError(f"{conf['name']}: registry entry "
+                             f"{conf['registry']!r} has {field} = "
+                             f"{getattr(base, field)!r}; the file must "
+                             f"state it ({' or '.join(keys)})")
+    return out
+
+
 def model_config(conf: dict):
     """The program's ModelConfig for a configuration file: the registry's
-    entry with every size the file states."""
+    entry with every size the file states (the dense keys, and those of
+    MAPPED that it holds)."""
     from repro.configs.base import get_config
-    cfg = get_config(conf["registry"]).replace(
+    base = get_config(conf["registry"])
+    cfg = base.replace(
         name=conf["name"], n_layers=conf["num_hidden_layers"],
         d_model=conf["hidden_size"], n_heads=conf["num_attention_heads"],
         n_kv_heads=conf["num_key_value_heads"],
@@ -78,8 +128,9 @@ def model_config(conf: dict):
         norm_type=conf["norm"], rope_theta=float(conf["rope_theta"]),
         tie_embeddings=bool(conf["tie_word_embeddings"]),
         norm_eps=float(conf.get("rms_norm_eps",
-                                conf.get("norm_epsilon", 1e-5))))
-    if cfg.head_dim != conf["head_dim"]:
+                                conf.get("norm_epsilon", 1e-5))),
+        **_mapped(conf, base))
+    if "head_dim" in conf and cfg.head_dim != conf["head_dim"]:
         raise ValueError(f"{conf['name']}: head_dim {conf['head_dim']} "
                          f"but the program derives {cfg.head_dim}")
     return cfg

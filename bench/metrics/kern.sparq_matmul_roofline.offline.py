@@ -3,17 +3,21 @@ least time the chip needs for the matmuls of the decode steps and
 prefill chunks that ran in the traced window (`costs/sparq_matmul.py`,
 per call the larger of ops over the int8 peak and bytes over HBM
 bandwidth), over the device time of its kernel there."""
+from bench.costs import layers
 from bench.costs import sparq_matmul as cost
 from bench.observe import least_time, per_execution
 
 
 def least(obs, m):
     s, p = obs.sizes, obs.peaks
-    t = 0.0
-    for k, n in cost.layer_shapes(s):
-        ops, nbytes = cost.call(m, k, n)
-        t += least_time(ops, nbytes, p[cost.PEAK], p["hbm_bytes_per_s"])
-    return t * s["layers"]
+    total = 0.0
+    for n_layers, kind in layers.groups(s):
+        t = 0.0
+        for k, n in cost.layer_shapes(s, kind):
+            ops, nbytes = cost.call(m, k, n)
+            t += least_time(ops, nbytes, p[cost.PEAK], p["hbm_bytes_per_s"])
+        total += t * n_layers
+    return total
 
 
 def read(obs):

@@ -15,6 +15,22 @@ CPU_PEAKS = {"bf16_flops": 1e12, "int8_ops": 2e12, "hbm_bytes_per_s": 1e11,
              "hbm_bytes": 1e9}
 
 
+#: the configuration file of a DeepSeek-V2-Lite-shaped model small enough
+#: for the CPU: one leading dense layer and two MoE layers, width 128, 8
+#: routed experts (top 2) and 2 shared of width 64, latent rank 32
+MOE_CONF = {
+    "name": "deepseek-tiny", "registry": "deepseek-v2-lite-16b",
+    "num_hidden_layers": 3, "hidden_size": 128, "num_attention_heads": 4,
+    "num_key_value_heads": 4, "intermediate_size": 256, "vocab_size": 512,
+    "hidden_act": "silu", "norm": "rmsnorm", "rms_norm_eps": 1e-6,
+    "rope_theta": 10000, "tie_word_embeddings": False,
+    "first_k_dense_replace": 1, "n_routed_experts": 8,
+    "num_experts_per_tok": 2, "moe_intermediate_size": 64,
+    "n_shared_experts": 2, "kv_lora_rank": 32, "qk_nope_head_dim": 32,
+    "qk_rope_head_dim": 16, "v_head_dim": 32, "weight_seed": 0,
+}
+
+
 def spec(name: str, limit: float = LIMIT, width: int = 128,
          vocab: int = 512) -> cell.Spec:
     full = cell.load_spec(name)

@@ -1,13 +1,14 @@
 """Seeded weights, drawn on the device.
 
 The benchmark owns the draw: every weight is a function of the
-configuration's weight seed, the leaf's path and its layer alone, so the
-reference can draw one layer at a time and get the very values the
-program was given. Matmul weights are truncated normals of std
-1/sqrt(d_in), the attention and MLP output projections OUT_GAIN times
-that and the query and key projections QK_GAIN times; the embedding and
-head std 0.02; norms at their identity values; all in bf16, the type
-they are drawn in before the program quantizes them.
+configuration's weight seed, the leaf's path, its layer and (in an
+expert bank) its expert alone, so the reference can draw one layer or
+expert at a time and get the very values the program was given. Matmul
+weights are truncated normals of std 1/sqrt(d_in), the attention and
+MLP output projections OUT_GAIN times that and the query and key
+projections QK_GAIN times; the embedding and head std 0.02; norms at
+their identity values; all in bf16, the type they are drawn in before
+the program quantizes them.
 
 Why OUT_GAIN: with the usual depth-scaled init, the token's own
 embedding dominates the residual stream, and a tied head then ranks the
@@ -23,6 +24,21 @@ decode step that dropped its own K/V moved the widest logit gap from
 0.05 to 0.09 at a small size. At twice that (score std 4, attention as
 peaked as a trained model's) it moved it to 1.5.
 
+Latent attention (MLA) scores q_nope . (rms(c_kv) @ w_uk) + q_pe . k_pe,
+where c_kv and k_pe come from w_dkv; the norm on c_kv cancels w_dkv's
+scale in the first term but not in the rope term. Measured over the
+first layer's 256 x 256 scores of seeded tokens (truncation at 2 std
+makes each factor 0.88 of its nominal std): the dense cells' scores have
+std 3.10 (width 128, 4 heads of 32) and 3.09 (StarCoder2-3B's widths).
+MLA at width 128 (4 heads, rank 32, nope 32, rope 16) and at
+DeepSeek-V2-Lite's widths (2048, 16 heads, rank 512, nope 128, rope 64)
+reads 1.55 and 1.55 with the gain on `wq` alone, 2.65 and 2.68 with it
+on `w_uk` too, and 3.09 and 3.09 with it on `w_uk` and `w_dkv`: so both
+carry QK_GAIN.
+
+Expert banks [E, d_in, d_out] are drawn one layer at a time like any
+other stacked weight, each expert from a key of its own.
+
 `served_params` builds the tree the program serves in one jitted call:
 each stacked matmul weight is drawn layer by layer and handed to the
 program's own `quantize_params`, so no float copy of all layers ever
@@ -37,10 +53,11 @@ import jax
 import jax.numpy as jnp
 
 #: leaves whose output feeds the residual stream
-OUT_PROJ = ("wo", "w_down")
+OUT_PROJ = ("wo", "w_down", "sh_down")
 OUT_GAIN = 8.0
-#: the query and key projections (sharper attention)
-QK_PROJ = ("wq", "wk")
+#: the query and key projections (sharper attention); in latent attention
+#: the key side is the latent's down- and up-projections
+QK_PROJ = ("wq", "wk", "w_uk", "w_dkv")
 QK_GAIN = 2.0
 
 
@@ -60,18 +77,40 @@ def leaf_key(key, path: str, layer) -> jax.Array:
     return jax.random.fold_in(k, layer)
 
 
+def _gain(path: str) -> float:
+    name = path.rsplit("/", 1)[-1]
+    if name in OUT_PROJ:
+        return OUT_GAIN
+    if name in QK_PROJ:
+        return QK_GAIN
+    return 1.0
+
+
+def _normal(k, path: str, shape: Tuple[int, int]) -> jnp.ndarray:
+    std = shape[0] ** -0.5 * _gain(path)
+    z = jax.random.truncated_normal(k, -2.0, 2.0, shape, jnp.float32)
+    return (z * std).astype(jnp.bfloat16)
+
+
 def draw_matrix(key, path: str, layer, shape: Tuple[int, int]
                 ) -> jnp.ndarray:
     """One layer's [d_in, d_out] matmul weight, bf16."""
-    std = shape[0] ** -0.5
-    name = path.rsplit("/", 1)[-1]
-    if name in OUT_PROJ:
-        std *= OUT_GAIN
-    elif name in QK_PROJ:
-        std *= QK_GAIN
-    z = jax.random.truncated_normal(leaf_key(key, path, layer), -2.0, 2.0,
-                                    shape, jnp.float32)
-    return (z * std).astype(jnp.bfloat16)
+    return _normal(leaf_key(key, path, layer), path, shape)
+
+
+def draw_expert(key, path: str, layer, expert, shape: Tuple[int, int]
+                ) -> jnp.ndarray:
+    """One expert's [d_in, d_out] weight in one layer's bank, bf16: each
+    expert has a key of its own, folded from its layer's."""
+    return _normal(jax.random.fold_in(leaf_key(key, path, layer), expert),
+                   path, shape)
+
+
+def draw_bank(key, path: str, layer, shape: Tuple[int, int, int]
+              ) -> jnp.ndarray:
+    """One layer's [E, d_in, d_out] expert bank, bf16."""
+    return jax.vmap(lambda e: draw_expert(key, path, layer, e, shape[1:]))(
+        jnp.arange(shape[0]))
 
 
 def draw_table(key, path: str, shape) -> jnp.ndarray:
@@ -113,9 +152,11 @@ def served_builder(model) -> Callable:
         def leaf(kp, sd):
             path = _path(kp)
             name = path.rsplit("/", 1)[-1]
-            if path.startswith("blocks/") and len(sd.shape) == 3:
+            if path.startswith("blocks/") and len(sd.shape) in (3, 4):
+                draw = draw_matrix if len(sd.shape) == 3 else draw_bank
+
                 def one(layer):
-                    w = draw_matrix(key, path, layer, sd.shape[1:])
+                    w = draw(key, path, layer, sd.shape[1:])
                     return quantize_params({name: w[None]})[name]
                 out = jax.lax.map(one, jnp.arange(sd.shape[0]))
                 return jax.tree.map(lambda a: a[:, 0], out)
@@ -126,21 +167,37 @@ def served_builder(model) -> Callable:
     return build
 
 
+def _drawer(seed: int, fn: Callable) -> Callable:
+    """`draw(path, shape, *indices)` -> fn(key, path, *indices, shape),
+    one jitted program per (path, shape)."""
+    key = base_key(seed)
+    jitted: Dict[Tuple[str, Tuple[int, ...]], Callable] = {}
+
+    def draw(path: str, shape, *indices) -> jnp.ndarray:
+        shape = tuple(shape)
+        f = jitted.get((path, shape))
+        if f is None:
+            f = jax.jit(lambda *i, p=path, s=shape: fn(key, p, *i, s))
+            jitted[(path, shape)] = f
+        return f(*(jnp.int32(i) for i in indices))
+    return draw
+
+
 def layer_drawer(seed: int) -> Callable:
     """For the reference: `draw(path, layer, shape)` -> the bf16
     [d_in, d_out] weight of one layer, the very values `served_params`
     handed to the program's quantizer."""
-    key = base_key(seed)
-    jitted: Dict[Tuple[str, Tuple[int, int]], Callable] = {}
+    draw = _drawer(seed, draw_matrix)
+    return lambda path, layer, shape: draw(path, shape, layer)
 
-    def draw(path: str, layer: int, shape: Tuple[int, int]) -> jnp.ndarray:
-        fn = jitted.get((path, shape))
-        if fn is None:
-            fn = jax.jit(lambda l, p=path, s=tuple(shape):
-                         draw_matrix(key, p, l, s))
-            jitted[(path, shape)] = fn
-        return fn(jnp.int32(layer))
-    return draw
+
+def expert_drawer(seed: int) -> Callable:
+    """For the reference: `draw(path, layer, expert, shape)` -> the bf16
+    [d_in, d_out] weight of one expert of one layer's bank, the very
+    values `served_params` handed to the program's quantizer."""
+    draw = _drawer(seed, draw_expert)
+    return lambda path, layer, expert, shape: draw(path, shape, layer,
+                                                   expert)
 
 
 def table_drawer(seed: int) -> Callable:
